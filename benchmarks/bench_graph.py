@@ -15,7 +15,7 @@ Overlap detection (AA^T, BELLA filter) compares the on-grid filter
 (``overlap_pairs``) against the pull-and-filter host oracle.
 
 ``run_graph_suite`` emits JSON rows for BENCH_graph.json: the masked vs
-unmasked *plans* (capacities, batch count, k-bin pairings), per-path wall-ms
+unmasked *plans* (capacities, batch count), per-path wall-ms
 and host-transfer bytes, and an acceptance summary asserting the §V-B claim
 (masked capacities and batch count strictly below unmasked on the R-MAT
 case). CPU wall times are NOT TPU predictions; the reproduced claim is the
@@ -37,7 +37,6 @@ from .common import emit
 
 
 def _plan_row(variant, plan):
-    kb = plan.kbin
     return dict(
         op="plan", variant=variant, wall_ms=0.0,
         batches=plan.num_batches,
@@ -45,7 +44,6 @@ def _plan_row(variant, plan):
         piece_cap=plan.caps.piece_cap, c_cap=plan.caps.c_cap,
         sel_cap=plan.sel_cap, mask_sel_cap=plan.mask_sel_cap,
         max_unmerged_nnz=plan.max_unmerged_nnz,
-        pairings=kb.pairings, pairings_unbinned=kb.pairings_unbinned,
     )
 
 

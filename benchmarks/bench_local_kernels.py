@@ -9,13 +9,11 @@ Our TPU adaptation compares, per hot-path op:
   * Merge-Fiber — unsorted lexsort-merge vs packed engines vs the segmented
     k-way merge that exploits already-sorted fiber pieces (merge, don't
     re-sort).
-  * Paired SpGEMM — O(capA×capB) pairing grid vs the k-binned grid
-    (``repro.kernels.spgemm_binned``), with the pairing-work counts that the
-    symbolic bin plan bounds.
+  * Local multiply — ESC vs the hash accumulator (scratch bytes).
 
 CPU wall times are NOT TPU predictions; the comparison shape (relative cost
-of keeping intermediates sorted / pairing everything against everything vs
-the binned + packed-key engines) is the reproduced claim. ``run_local_suite``
+of keeping intermediates sorted vs the packed-key engines) is the
+reproduced claim. ``run_local_suite``
 emits machine-readable rows for BENCH_local_kernels.json (op, variant,
 wall_ms, achieved gflops) so the perf trajectory is tracked PR over PR.
 """
@@ -29,8 +27,6 @@ from repro.core import local_spgemm as lsp
 from repro.core import semiring as sr
 from repro.core import sparse as sp
 from repro.core import symbolic as sym
-from repro.kernels import ops
-from repro.kernels.spgemm_binned import pairing_counts
 
 from .common import emit, time_jit
 
@@ -166,42 +162,6 @@ def bench_hash_vs_esc(rows_out=None, n=256, nnz_per_row=16):
     return scratch_esc / max(scratch_hash, 1)
 
 
-def bench_binned_pairing(rows_out=None, scale=7, edge_factor=8):
-    """Paired SpGEMM: unbinned O(capA×capB) vs the k-binned plan on a
-    skewed-k (R-MAT) workload — the regime binning targets."""
-    a = gen.rmat(scale=scale, edge_factor=edge_factor, seed=3)
-    b = gen.rmat(scale=scale, edge_factor=edge_factor, seed=4)
-    plan = sym.plan_k_bins(
-        np.asarray(a.col_counts()), np.asarray(b.row_counts()), a.cap, b.cap
-    )
-    pc = pairing_counts(a.cap, b.cap, plan.num_bins, plan.bin_cap_a,
-                        plan.bin_cap_b)
-    t_full = time_jit(lambda x, y: ops.spgemm_paired(x, y), a, b)
-    bm = jnp.asarray(plan.bin_of_k)
-    t_bin = time_jit(
-        lambda x, y, z: ops.spgemm_paired_binned(
-            x, y, plan.num_bins, plan.bin_cap_a, plan.bin_cap_b, bin_map=z
-        )[0],
-        a, b, bm,
-    )
-    _note(rows_out, **dict(
-        op="paired_spgemm", variant="unbinned", wall_ms=t_full / 1e3,
-        gflops=2 * pc["pairings_unbinned"] / t_full / 1e3,
-        pairings=pc["pairings_unbinned"],
-    ))
-    _note(rows_out, **dict(
-        op="paired_spgemm", variant="binned", wall_ms=t_bin / 1e3,
-        gflops=2 * pc["pairings_binned"] / t_bin / 1e3,
-        pairings=pc["pairings_binned"], num_bins=plan.num_bins,
-        pairing_reduction=pc["reduction"],
-    ))
-    emit("tableVII/paired_unbinned", t_full,
-         f"pairings={pc['pairings_unbinned']}")
-    emit("tableVII/paired_binned", t_bin,
-         f"pairings={pc['pairings_binned']} ({pc['reduction']:.1f}x fewer)")
-    return pc["reduction"]
-
-
 def run(n: int = 256, nnz_per_row: int = 8, layers: int = 4) -> None:
     """CSV suite (paper Table VII shape) — kept for ``benchmarks.run`` all."""
     a = gen.erdos_renyi(n, nnz_per_row, seed=1)
@@ -242,7 +202,6 @@ def run(n: int = 256, nnz_per_row: int = 8, layers: int = 4) -> None:
 
     bench_coalesce()
     bench_merge()
-    bench_binned_pairing()
     bench_hash_vs_esc()
 
 
@@ -252,12 +211,11 @@ def run_local_suite() -> list:
     rows = []
     coal = bench_coalesce(rows)
     merg = bench_merge(rows)
-    red = bench_binned_pairing(rows)
     scratch = bench_hash_vs_esc(rows)
     rows.append(dict(
         op="summary", variant="acceptance",
         wall_ms=0.0, gflops=0.0,
-        coalesce_speedup=coal, merge_speedup=merg, pairing_reduction=red,
+        coalesce_speedup=coal, merge_speedup=merg,
         hash_scratch_reduction=scratch,
     ))
     return rows

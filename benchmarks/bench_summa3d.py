@@ -9,8 +9,7 @@ pipeline busy (§IV-A, Alg. 4):
     dispatching the next batch (the pre-pipelining schedule).
   * pipelined: batches i+1..i+lookahead dispatched before batch i's flags
     are read; consumer host work overlaps device compute.
-  * binned vs ESC vs hash-accumulator local multiply on the same plan, with
-    the pairing-work counts the symbolic k-bin plan bounds.
+  * ESC vs hash-accumulator local multiply on the same plan.
 
 The suite also emits the hash path's MEMORY claim as a plan row: at a fixed
 ``per_process_memory`` (the probe budget that forces the ESC plan to batch),
@@ -18,10 +17,9 @@ the hash memory model — table slots over the merged output instead of the
 full expansion — plans strictly fewer batches.
 
 CPU wall times are NOT TPU predictions; the reproduced claim is the shape of
-the comparison (host-sync per batch vs windowed async dispatch, full pairing
-grid vs k-binned). ``run_summa3d_suite`` emits JSON rows for
-BENCH_summa3d.json: per-batch wall-ms, end-to-end wall-ms per driver, the
-pairing counts, and an acceptance summary row.
+the comparison (host-sync per batch vs windowed async dispatch).
+``run_summa3d_suite`` emits JSON rows for BENCH_summa3d.json: per-batch
+wall-ms, end-to-end wall-ms per driver, and an acceptance summary row.
 """
 import time
 
@@ -71,7 +69,7 @@ def _consumer_factory(n, grid):
     return state, consumer
 
 
-def _run_once(A, B, grid, nb, pipelined, binned, local_path="auto"):
+def _run_once(A, B, grid, nb, pipelined, local_path="auto"):
     """One timed end-to-end driver run; returns (wall_ms, batch_ms, result)."""
     n = A.shape[0]
     state, consumer = _consumer_factory(n, grid)
@@ -81,7 +79,7 @@ def _run_once(A, B, grid, nb, pipelined, binned, local_path="auto"):
         A, B, grid, per_process_memory=1 << 30, consumer=consumer,
         path="sparse",
         spec=PlanSpec(local_path=local_path, force_num_batches=nb),
-        exec_spec=ExecSpec(pipelined=pipelined, binned=binned),
+        exec_spec=ExecSpec(pipelined=pipelined),
     )
     dt = (time.perf_counter() - t0) * 1e3
     return dt, state["batch_ms"], res
@@ -98,9 +96,8 @@ def _time_drivers(A, B, grid, nb, configs, iters=5):
     batch_ms = {name: None for name in configs}
     results = {}
     for it in range(iters + 1):
-        for name, (pipelined, binned, local_path) in configs.items():
-            dt, bms, res = _run_once(A, B, grid, nb, pipelined, binned,
-                                     local_path)
+        for name, (pipelined, local_path) in configs.items():
+            dt, bms, res = _run_once(A, B, grid, nb, pipelined, local_path)
             results[name] = res
             if it == 0:
                 continue
@@ -119,20 +116,6 @@ def run_summa3d_suite(scale=8, edge_factor=8, nb=32, iters=5) -> list:
     A = scatter_to_grid(a, grid, "A")
     B = scatter_to_grid(b, grid, "B")
     rows = []
-
-    plan = plan_batches(A, B, grid, per_process_memory=1 << 30,
-                        spec=PlanSpec(force_num_batches=nb, local_path="esc"))
-    reduction = plan.kbin.pairings_unbinned / max(plan.kbin.pairings, 1)
-    rows.append(dict(
-        op="plan", variant="kbin", wall_ms=0.0, n=n,
-        num_batches=plan.num_batches, num_bins=plan.kbin.num_bins,
-        pairings_binned=plan.kbin.pairings,
-        pairings_unbinned=plan.kbin.pairings_unbinned,
-        pairing_reduction=reduction,
-    ))
-    emit("fig4/summa3d_plan", 0.0,
-         f"b={plan.num_batches} pairings={plan.kbin.pairings}"
-         f"({reduction:.1f}x fewer)")
 
     # --- the hash path's memory claim: at the SAME fixed per-process budget
     # (probed so the ESC plan must batch), the hash plan needs fewer
@@ -162,11 +145,10 @@ def run_summa3d_suite(scale=8, edge_factor=8, nb=32, iters=5) -> list:
          f"cf={p_hash.compression_est:.2f}")
 
     configs = {
-        "serial": (False, "auto", "auto"),
-        "pipelined": (True, "auto", "auto"),
-        "pipelined_esc": (True, False, "esc"),
-        "pipelined_binned": (True, True, "binned"),
-        "pipelined_hash": (True, "auto", "hash"),
+        "serial": (False, "auto"),
+        "pipelined": (True, "auto"),
+        "pipelined_esc": (True, "esc"),
+        "pipelined_hash": (True, "hash"),
     }
     times, batch_ms, results = _time_drivers(A, B, grid, nb, configs,
                                              iters=iters)
@@ -189,10 +171,6 @@ def run_summa3d_suite(scale=8, edge_factor=8, nb=32, iters=5) -> list:
     rows.append(dict(
         op="summary", variant="acceptance", wall_ms=0.0,
         speedup_pipelined_vs_serial=speedup,
-        pairing_reduction=reduction,
-        pairings_binned=plan.kbin.pairings,
-        pairings_unbinned=plan.kbin.pairings_unbinned,
-        binned_local_multiply_used=bool(res.binned),
         local_path_used=res.local_path,
         num_batches_esc=p_esc.num_batches,
         num_batches_hash=p_hash.num_batches,

@@ -39,7 +39,6 @@ BENCH_PPM = 1 << 30
 PIPELINED_VARIANTS = {
     "pipelined": "auto",
     "pipelined_esc": "esc",
-    "pipelined_binned": "binned",
     "pipelined_hash": "hash",
 }
 

@@ -64,7 +64,6 @@ SUITE_ROWS = {
         # carry the two autotuner acceptance criteria
         ("model", "pipelined"): ("ratio", "within_band"),
         ("model", "pipelined_esc"): ("ratio", "within_band"),
-        ("model", "pipelined_binned"): ("ratio", "within_band"),
         ("model", "pipelined_hash"): ("ratio", "within_band"),
         ("summary", "model_acceptance"): ("overhead", "all_within_band"),
         ("autotune", "skew"): (

@@ -9,11 +9,11 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 ``--suite all`` (default) prints ``name,us_per_call,derived`` CSV across every
 table/figure module. ``--suite local`` runs the local-kernel hot-path suite
-(packed-key sort engine + k-binned pairing) and writes
+(packed-key sort engine + hash-vs-ESC scratch) and writes
 ``BENCH_local_kernels.json`` at the repo root — op, variant, wall-ms, achieved
 GFLOP/s per row — so the perf trajectory is tracked from PR to PR.
 ``--suite summa3d`` runs the end-to-end batched driver suite (pipelined vs
-serial schedule, binned vs ESC vs hash-accumulator local multiply, plus the
+serial schedule, ESC vs hash-accumulator local multiply, plus the
 fixed-memory hash-vs-ESC batch-count row) and writes ``BENCH_summa3d.json``,
 refreshing ``BENCH_local_kernels.json`` in the same run so both perf files
 stay in lockstep; ``--smoke`` shrinks it to CI-sized shapes with the same

@@ -18,7 +18,7 @@ def main() -> None:
     import numpy as np
     import jax
     import jax.numpy as jnp
-    from repro.compat import AxisType, make_mesh, set_mesh
+    from jax.sharding import AxisType
 
     from repro.configs import get_config
     from repro.models import transformer as tfm
@@ -31,7 +31,7 @@ def main() -> None:
     )
 
     cfg = get_config("deepseek-moe-16b", smoke=True)
-    mesh = make_mesh((2, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((2, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
     # --- the dispatch matrix, explicitly
     mcfg = cfg.moe
@@ -58,7 +58,7 @@ def main() -> None:
     # --- full model forward with EP over the "model" axis
     params = tfm.init_params(cfg, jax.random.PRNGKey(2))
     tokens = jax.random.randint(jax.random.PRNGKey(4), (4, 16), 0, cfg.vocab)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits, aux = tfm.forward(cfg, params, tokens, mesh)
     print(f"full MoE model forward on 2×2 mesh: logits {logits.shape}, "
           f"aux={float(aux):.4f} — OK")

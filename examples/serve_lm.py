@@ -22,7 +22,7 @@ def main() -> None:
     import numpy as np
 
     import jax
-    from repro.compat import AxisType, make_mesh, set_mesh
+    from jax.sharding import AxisType
 
     from repro.configs import get_config
     from repro.models import transformer as tfm
@@ -30,9 +30,9 @@ def main() -> None:
 
     cfg = get_config(args.arch, smoke=True)
     ndev = len(jax.devices())
-    mesh = make_mesh((1, min(2, ndev)), ("data", "model"),
+    mesh = jax.make_mesh((1, min(2, ndev)), ("data", "model"),
                          axis_types=(AxisType.Auto,) * 2)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = tfm.init_params(cfg, jax.random.PRNGKey(3))
         eng = ServeEngine(cfg, params, mesh, EngineConfig(max_batch=3, s_max=64))
         rng = np.random.default_rng(0)

@@ -29,7 +29,7 @@ def main() -> None:
     args = ap.parse_args()
 
     import jax
-    from repro.compat import AxisType, make_mesh, set_mesh
+    from jax.sharding import AxisType
 
     from repro.configs import get_config
     from repro.data import DataConfig, synthetic_batch
@@ -40,7 +40,7 @@ def main() -> None:
     from repro.train import TrainConfig, build_train_step
 
     ndev = len(jax.devices())
-    mesh = make_mesh((max(ndev // 2, 1), min(2, ndev)), ("data", "model"),
+    mesh = jax.make_mesh((max(ndev // 2, 1), min(2, ndev)), ("data", "model"),
                          axis_types=(AxisType.Auto,) * 2)
 
     if args.arch:
@@ -72,7 +72,7 @@ def main() -> None:
         return {"params": params, "opt": adamw.init_opt_state(params)}
 
     def wrapped_step(state, batch):
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             p, o, m = step_fn(state["params"], state["opt"], batch)
         return {"params": p, "opt": o}, m
 

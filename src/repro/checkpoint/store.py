@@ -15,8 +15,8 @@ Design points for 1000+-node deployments (scaled-down here, same contract):
     never corrupts the latest-complete checkpoint; stale ``step_*.tmp``
     leftovers from a mid-write kill are swept on the next `latest_step`;
   * a free-form ``meta`` dict rides in the manifest — the SpGEMM loops use
-    it to snapshot the **plan signature** (pow2/floor caps, pinned k-bin
-    signature, hash caps, local path, batch-count floor) next to the iterate
+    it to snapshot the **plan signature** (pow2/floor caps, hash caps,
+    local path, batch-count floor) next to the iterate
     so a restored run rebuilds the identical fused-step executable with zero
     extra retraces (see `runtime/resilient.py`);
   * `AsyncCheckpointer` runs the host-gather + write on a worker thread,

@@ -10,13 +10,11 @@ communicators once symbolic planning is done):
      columns — the paper's observation that the symbolic step has the same
      communicator structure but a far lighter payload (§IV-A, Fig. 8). The
      same pass also emits B's per-column entry counts (exact per-batch
-     selection capacities — no heuristic, no spurious selection retries) and
-     the per-k count vectors of the *gathered* operands, from which the
-     k-bin plan for the paired local multiply is derived.
+     selection capacities — no heuristic, no spurious selection retries).
   2. Host-side batch planning: b from Alg. 3 line 12 (+ Eq. 2 lower-bound
      check), rounded up for block-cyclic divisibility; static capacities for
-     the numeric pass derived from the symbolic per-column vectors; a
-     ``KBinPlan`` sizing the k-binned local multiply. This is the paper's
+     the numeric pass derived from the symbolic per-column vectors; the
+     local-multiply path (ESC or hash). This is the paper's
      symbolic→numeric split — in JAX it also fixes the static shapes the
      compiler needs.
   3. Pipelined per-batch schedule: selection + multiply are FUSED into one
@@ -52,15 +50,14 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 
 from . import semiring as sr
-from ..compat import shard_map
+from . import sortkeys
 from .distsparse import DistSparse, dist_spec
 from .grid import COL_AX, LAYER_AX, ROW_AX, Grid
 from .summa3d import (
     BatchCaps,
-    BinnedCaps,
     HashCaps,
     _squeeze_tile,
     summa3d_dense_step,
@@ -73,19 +70,19 @@ from .specs import ExecSpec, PlanFloors, PlanSpec, resolve_specs
 from .symbolic import (
     HASH_LOAD_FACTOR,
     HASH_SLOT_BYTES,
-    KBinPlan,
     SymbolicCounts,
     batch_count,
     batch_count_lower_bound,
+    dense_step_bytes,
+    esc_step_bytes,
     estimate_mem_c_bytes,
-    plan_k_bins,
     rup8 as _rup8,
     rup_pow2 as _rup_pow2,
 )
 
 # auto-dispatch threshold: the hash path pays a per-chunk insert pass, so it
 # must buy at least this compression factor (flops per merged survivor)
-# before the plan prefers it over ESC/binned.
+# before the plan prefers it over ESC.
 HASH_CF_THRESHOLD = 2.0
 
 # partial products enumerated per reused chunk buffer of the hash path
@@ -97,14 +94,14 @@ _dense_jit = jax.jit(summa3d_dense_step, static_argnames=("grid", "semiring"))
 _sparse_jit = jax.jit(
     summa3d_sparse_step,
     static_argnames=(
-        "grid", "caps", "semiring", "sorted_merge", "kbin", "hashc",
+        "grid", "caps", "semiring", "sorted_merge", "hashc",
     ),
 )
 _fused_jit = jax.jit(
     summa3d_fused_step,
     static_argnames=(
         "grid", "num_batches", "sel_cap", "caps", "semiring", "sorted_merge",
-        "path", "kbin", "hashc", "mask_cap", "mask_complement",
+        "path", "hashc", "mask_cap", "mask_complement",
     ),
 )
 
@@ -158,18 +155,10 @@ def _symbolic3d_jit(
         # sum over the row group -> each process reads its own row
         percol_all = lax.psum(percol_all, ROW_AX)
         percol = percol_all[i_own]
-        # extras for the numeric pass, free on the same communicators:
-        # B per-column entry counts (exact selection capacities) and the
-        # per-k counts of the gathered operands (k-bin plan input).
+        # extra for the numeric pass, free on the same communicators:
+        # B per-column entry counts (exact selection capacities)
         bcc = b_loc.col_counts()  # (tn_b,)
-        rc_local = b_loc.row_counts()  # (wl,)
-        rc_full = lax.all_gather(rc_local, ROW_AX).reshape(-1)  # (k_tot,)
-        outs = (
-            percol[None, None, None],
-            bcc[None, None, None],
-            cc_full[None, None, None],
-            rc_full[None, None, None],
-        )
+        outs = (percol[None, None, None], bcc[None, None, None])
         if rest:
             # exact per-(tile, local column) mask counts, on-grid
             mcc = _squeeze_tile(rest[0]).col_counts()  # (wl,)
@@ -178,7 +167,7 @@ def _symbolic3d_jit(
 
     spec3 = jax.sharding.PartitionSpec(ROW_AX, COL_AX, LAYER_AX)
     in_specs = [dist_spec(d, spec3) for d in (a, b)]
-    out_specs = (spec3, spec3, spec3, spec3)
+    out_specs = (spec3, spec3)
     args = [a, b]
     if mask is not None:
         in_specs.append(dist_spec(mask, spec3))
@@ -226,17 +215,13 @@ def symbolic3d_counts(
     if mask is not None:
         assert mask.kind in ("A", "C"), mask.kind
         assert mask.shape == (a.shape[0], b.shape[1]), (mask.shape, a.shape, b.shape)
-        percol, bcc, cc_full, rc_full, mcc = _symbolic3d_jit(a, b, mask, grid)
+        percol, bcc, mcc = _symbolic3d_jit(a, b, mask, grid)
         mask_cc = np.asarray(mcc).astype(np.int64)
     else:
-        percol, bcc, cc_full, rc_full = _symbolic3d_jit(a, b, None, grid)
-    # cc_full is a function of (row block, layer) only; rc_full of
-    # (col block, layer) only — slice the redundant grid axes away.
+        percol, bcc = _symbolic3d_jit(a, b, None, grid)
     return SymbolicCounts(
         percol=np.asarray(percol),
         b_colcounts=np.asarray(bcc),
-        a_kcounts=np.asarray(cc_full)[:, 0],  # (pr, l, k_tot)
-        b_kcounts=np.asarray(rc_full)[0],  # (pc, l, k_tot)
         mask_colcounts=mask_cc,
     )
 
@@ -267,25 +252,11 @@ class BatchPlan:
     max_unmerged_nnz: int  # max over processes, b=1 (mask-filtered if masked)
     per_batch_flops: np.ndarray  # (num_batches,) global flops per batch
     sel_cap: int = 0  # exact per-batch selection capacity (B entries)
-    kbin: Optional[KBinPlan] = None  # k-bin plan for the paired local multiply
     mask_sel_cap: int = 0  # exact per-batch mask-slice capacity (masked only)
     local_path: str = "esc"  # plan-driven local-multiply decision (b=1 view)
     hash_caps: Optional[HashCaps] = None  # static hash caps (local_path="hash")
     compression_est: float = 1.0  # flops per merged survivor (b=1, max proc)
-
-    @property
-    def binned_profitable(self) -> bool:
-        """Plan-driven switch: does k-binning strictly cut pairing work?
-
-        Requires real bin structure (num_bins > 1): with a single bin the
-        capacity-product baseline still shrinks (compaction drops padding),
-        but there is no structural reduction to pay the binning pass for.
-        """
-        return (
-            self.kbin is not None
-            and self.kbin.num_bins > 1
-            and self.kbin.pairings < self.kbin.pairings_unbinned
-        )
+    path_reason: str = ""  # why the planner picked ``local_path`` (and b)
 
 
 def plan_batches(
@@ -306,18 +277,22 @@ def plan_batches(
     call (no spec) keeps the historical ``local_path="esc"`` default; a
     passed spec uses its own default ("auto" — the driver's semantics).
 
-    ``spec.local_path`` drives the 3-way local-multiply decision recorded on
-    the plan: "esc" and "binned" keep the classic O(flops)-scratch budget;
-    "hash" budgets the hash-accumulator path at O(nnz_out·load_factor)
-    resident bytes instead of O(flops) — high compression-factor multiplies
-    then need strictly fewer batches at the same ``per_process_memory``;
-    "auto" picks "hash" when the estimated compression factor clears
-    ``HASH_CF_THRESHOLD`` (the binned-vs-ESC refinement stays with the
-    driver, which knows the semiring). ``floors.hash_caps`` floors the
-    derived ``HashCaps`` elementwise (iterated-multiply jit-cache
-    stability, like ``floors.caps``); ``floors.kbin_caps`` additionally pins
-    the k-bin candidate list to its bin count when the spec leaves
-    ``kbin_candidates`` unset.
+    ``spec.local_path`` drives the local-multiply decision recorded on the
+    plan. Each path is budgeted at what its compiled step holds: "esc" at
+    ``ESC_SLOT_RECORDS`` r-byte records per expansion slot (expansion,
+    sort and outputs, slack included); "hash" at the hash-accumulator's
+    O(nnz_out·load_factor) resident bytes instead of O(flops) — high
+    compression-factor multiplies then need strictly fewer batches at the
+    same ``per_process_memory``; "dense" (set by the driver for
+    ``path="dense"``) at the dense step's f32 buffers, whose gather of one
+    B row per gathered A entry grows with the batch width; "auto" picks
+    "hash" when the estimated compression factor clears
+    ``HASH_CF_THRESHOLD`` and ESC otherwise. The hash table packs a D tile's
+    (row, col) into one i32 key, so the plan only runs hash where that key
+    fits: a forced "hash" raises b until it does, "auto" takes ESC instead.
+    ``plan.path_reason`` says which rule decided. ``floors.hash_caps``
+    floors the derived ``HashCaps`` elementwise (iterated-multiply
+    jit-cache stability, like ``floors.caps``).
 
     ``spec.reserved_bytes`` is subtracted from the per-process budget before the
     Alg. 3 batch count: memory the caller has already committed per process
@@ -371,6 +346,7 @@ def plan_batches(
         cap_b=b.cap,
         p=grid.p,
         cap_mask=spec.mask.cap if spec.mask is not None else None,
+        k_dim=a.shape[1],
     )
     return plan_from_symbolic(counts, inputs, per_process_memory, spec, floors)
 
@@ -391,6 +367,7 @@ class PlanInputs:
     cap_b: int
     p: int  # process count pr*pc*l
     cap_mask: Optional[int] = None
+    k_dim: int = 0  # contraction dimension (A's columns; dense-path plans)
 
     @classmethod
     def from_host(cls, a, b, grid_shape: Tuple[int, int, int],
@@ -421,6 +398,7 @@ class PlanInputs:
                 _cap(host_tile_counts(mask, grid_shape, "C"))
                 if mask is not None else None
             ),
+            k_dim=a.shape[1],
         )
 
 
@@ -448,11 +426,6 @@ def plan_from_symbolic(
     caps_pow2, caps_floor = floors.caps_pow2, floors.caps
     sel_cap_floor, num_batches_floor = floors.sel_cap, floors.num_batches
     hash_caps_floor = floors.hash_caps
-    kbin_candidates = spec.kbin_candidates
-    if kbin_candidates is None and floors.kbin_caps is not None:
-        # a pinned-bin-count floor implies the candidate pin the old API
-        # made every iterated caller thread separately
-        kbin_candidates = (floors.kbin_caps.num_bins,)
     if reserved_bytes >= per_process_memory:
         raise MemoryError(
             f"reserved output bytes ({reserved_bytes}) exceed per-process "
@@ -484,7 +457,7 @@ def plan_from_symbolic(
 
     # hash-path resident bound (O(output)): the table holds MERGED
     # survivors, and a D-tile column cannot exceed tm_a distinct rows
-    assert local_path in ("auto", "esc", "binned", "hash"), local_path
+    assert local_path in ("auto", "esc", "hash", "dense"), local_path
     tm_a = inputs.tm_a
     max_hash_nnz = int(np.minimum(merged_d_percol, tm_a).sum(axis=-1).max())
     compression_est = max_unmerged / max(max_hash_nnz, 1)
@@ -492,30 +465,75 @@ def plan_from_symbolic(
         local_path == "auto" and compression_est >= HASH_CF_THRESHOLD
     )
 
-    if force_num_batches is not None:
-        nb = force_num_batches
-    else:
-        if budget_hash:
-            # the stored intermediate is the table, not the expansion:
-            # convert its byte footprint back to r-byte units for Alg. 3
-            hash_bytes = estimate_mem_c_bytes(
+    def batches_for(hash_budget: bool) -> int:
+        if force_num_batches is not None:
+            return dist.round_batches(tn_b, force_num_batches, l)
+        # the compiled step's bytes at b = 1, converted back to r-byte units
+        # for Alg. 3 (every term is linear in the batch width)
+        if local_path == "dense":
+            # (pc gathered A tiles) x width gathers, B block and accumulator
+            step_bytes = dense_step_bytes(
+                tn_b, pc * inputs.cap_a, inputs.k_dim // l, tm_a
+            )
+        elif hash_budget:
+            # the stored intermediate is the table, not the expansion
+            step_bytes = estimate_mem_c_bytes(
                 max_unmerged, compression_est, r_bytes,
                 local_path="hash", load_factor=HASH_LOAD_FACTOR,
             )
-            budget_nnz = max(-(-hash_bytes // r_bytes), 1)
         else:
-            budget_nnz = max_unmerged
+            # ESC: the expansion, sort and outputs of flops_cap slots, which
+            # carry the plan's slack over the counted (survivor) products
+            step_bytes = esc_step_bytes(slack * max_unmerged, r_bytes)
+        budget_nnz = max(-(-step_bytes // r_bytes), 1)
         # num_batches is part of the fused step's static signature; the
         # floor (a previous iteration's count — more batches is always
         # valid) keeps iterated multiplies on one executable as nnz drifts.
-        nb = max(
+        nb_ = max(
             batch_count(
                 budget_nnz, max_nnz_a, max_nnz_b, per_process_memory,
                 r=r_bytes,
             ),
             num_batches_floor,
         )
-    nb = dist.round_batches(tn_b, nb, l)
+        return dist.round_batches(tn_b, nb_, l)
+
+    def hash_key_fits(nb_: int) -> bool:
+        # the hash table packs the (row, col) of a tm_a × wb D tile into
+        # one i32 key (spgemm_hash asserts the same)
+        return sortkeys.fits_i32(tm_a, tn_b // nb_)
+
+    nb = batches_for(budget_hash)
+    if local_path == "dense":
+        path_reason = "dense path"
+    elif local_path == "auto":
+        path_reason = (
+            f"auto: compression {compression_est:.3g} "
+            f"{'>=' if budget_hash else '<'} {HASH_CF_THRESHOLD}"
+        )
+    else:
+        path_reason = f"{local_path} requested"
+    if budget_hash and not hash_key_fits(nb):
+        if local_path == "hash":
+            if force_num_batches is not None:
+                raise ValueError(
+                    f"hash path forced at {nb} batches, but the packed key "
+                    f"of a {tm_a}x{tn_b // nb} D tile overflows i32"
+                )
+            nb0 = nb
+            while not hash_key_fits(nb):
+                nb = dist.round_batches(tn_b, nb + 1, l)
+            path_reason += (
+                f"; b raised {nb0} -> {nb} so the packed key of a "
+                f"{tm_a}x{tn_b // nb} D tile fits i32"
+            )
+        else:
+            budget_hash = False
+            path_reason += (
+                f", but the packed key of a {tm_a}x{tn_b // nb} D tile "
+                f"overflows i32 -> esc"
+            )
+            nb = batches_for(False)
 
     # per-(process, batch, piece) flops via the distribution's fold
     flops_pbp = dist.fold(percol, nb, l)  # (pr,pc,l,nb,l)
@@ -570,22 +588,6 @@ def plan_from_symbolic(
         ))
     sel_cap = max(sel_cap, sel_cap_floor)
 
-    # k-bin plan for the gathered pairing: per-k count vectors bounded
-    # element-wise over (block, layer) so the static caps hold on every
-    # process; gathered capacities are pc·capA / pr·sel_cap slots.
-    # ``kbin_candidates`` pins the bin-count choice (iterated multiplies pin
-    # it to the previous iteration's bin count for jit-cache stability).
-    kbin_kwargs = (
-        {"candidates": tuple(kbin_candidates)} if kbin_candidates else {}
-    )
-    kbin = plan_k_bins(
-        counts.a_kcounts.max(axis=(0, 1)),
-        counts.b_kcounts.max(axis=(0, 1)),
-        pc * inputs.cap_a,
-        pr * sel_cap,
-        **kbin_kwargs,
-    )
-
     # Eq. (2) lower bound (global memory form) for reporting/validation
     mem_c = r_bytes * int(per_process_flops.sum())
     try:
@@ -599,16 +601,9 @@ def plan_from_symbolic(
     # plan-driven local-multiply decision + static hash caps. Both derive
     # from the already-quantized/floored capacities, so iterated runs with
     # pow2 caps keep ONE fused-step executable per decided path.
-    if budget_hash:
-        decided = "hash"
-    elif local_path in ("esc", "binned"):
-        decided = local_path
-    else:  # auto, hash not profitable: structural binned-vs-ESC preference
-        decided = (
-            "binned"
-            if kbin.num_bins > 1 and kbin.pairings < kbin.pairings_unbinned
-            else "esc"
-        )
+    decided = "dense" if local_path == "dense" else (
+        "hash" if budget_hash else "esc"
+    )
     hash_caps = None
     if decided == "hash":
         chunk = min(caps.flops_cap, _rup8(HASH_CHUNK_CAP))
@@ -638,11 +633,11 @@ def plan_from_symbolic(
         max_unmerged_nnz=max_unmerged,
         per_batch_flops=per_batch_flops,
         sel_cap=sel_cap,
-        kbin=kbin,
         mask_sel_cap=mask_sel_cap,
         local_path=decided,
         hash_caps=hash_caps,
         compression_est=float(compression_est),
+        path_reason=path_reason,
     )
 
 
@@ -651,7 +646,8 @@ def probe_memory_budget(
     r_bytes: int = 12, fraction: int = 3, floor: int = 256,
 ) -> int:
     """A per-process budget that forces the (unmasked) plan to batch:
-    inputs plus 1/``fraction`` of the probed unmerged output.
+    inputs plus 1/``fraction`` of the ESC step's bytes for the probed
+    unmerged output (so the ESC plan takes about ``fraction`` batches).
 
     Shared by the graph bench and the slow-lane R-MAT cases so both assert
     the §V-B masked-vs-unmasked claim against the SAME budget math (the
@@ -662,7 +658,8 @@ def probe_memory_budget(
     inputs = r_bytes * (
         int(np.asarray(a.nnz).max()) + int(np.asarray(b.nnz).max())
     )
-    return inputs + max(r_bytes * probe.max_unmerged_nnz // fraction, floor)
+    step = esc_step_bytes(PlanSpec().slack * probe.max_unmerged_nnz, r_bytes)
+    return inputs + max(step // fraction, floor)
 
 
 def batch_column_map(n: int, grid: Grid, num_batches: int, batch: int) -> np.ndarray:
@@ -743,16 +740,17 @@ def plan_footprint(
     max_nnz_b: int,
     reserved_bytes: int = 0,
 ) -> int:
-    """Per-process bytes a capacity plan commits to, aligned with Alg. 3's
-    budget: ``r`` bytes per stored entry of inputs + selection + the batch's
-    stored intermediate (ESC/binned expansion scratch, or the hash table +
-    merged survivors). The retry ladder prices cap doublings against this
-    model, and the serving engine prices each admitted request with it.
+    """Per-process bytes a capacity plan commits to, aligned with the
+    planner's budget: ``r`` bytes per stored entry of inputs + selection +
+    the batch's step (``ESC_SLOT_RECORDS`` r-byte records per ESC expansion
+    slot, or the hash table + merged survivors). The retry ladder prices
+    cap doublings against this model, and the serving engine prices each
+    admitted request with it.
     """
     if hash_caps is not None:
         inter = hash_caps.table_cap * HASH_SLOT_BYTES + r_bytes * caps.d_cap
     else:
-        inter = r_bytes * caps.flops_cap
+        inter = esc_step_bytes(caps.flops_cap, r_bytes)
     return r_bytes * (max_nnz_a + max_nnz_b + sel_cap) + inter + reserved_bytes
 
 
@@ -812,8 +810,6 @@ class BatchedResult:
     plan: BatchPlan
     num_retries: int
     consumed: list  # consumer outputs per batch
-    binned: bool = False  # did the sparse local multiply run k-binned?
-    binned_caps: Optional[BinnedCaps] = None  # the static BinnedCaps used
     local_path: str = "esc"  # local multiply actually executed
     hash_caps: Optional[HashCaps] = None  # the static HashCaps used (hash)
     report: RunReport = dataclasses.field(default_factory=RunReport)
@@ -821,13 +817,12 @@ class BatchedResult:
     def floors(self) -> PlanFloors:
         """The capacities this run actually used, as a `PlanFloors` an
         iterated caller merges into its next plan — ONE field replaces the
-        old caps/sel/nb/kbin/hash attribute quintet (pow2 quantization on,
+        old caps/sel/nb/hash attribute quartet (pow2 quantization on,
         since that is the whole point of pinning)."""
         return PlanFloors(
             caps=self.plan.caps,
             sel_cap=self.plan.sel_cap,
             num_batches=self.plan.num_batches,
-            kbin_caps=self.binned_caps,
             hash_caps=self.hash_caps,
             caps_pow2=True,
         )
@@ -850,7 +845,7 @@ def batched_summa3d(
     """Multiply A·B in batches; the consumer sees each batch then it's freed.
 
     The knob surface is three frozen specs: ``spec`` (`PlanSpec` — mask,
-    local path, slack, reserved bytes, k-bin candidates), ``floors``
+    local path, slack, reserved bytes), ``floors``
     (`PlanFloors` — cross-iteration capacity pins, fold a previous run's
     ``BatchedResult.floors()`` in via ``merged()``), and ``exec_spec``
     (`ExecSpec` — pipelined schedule, lookahead, retry budget, degradation).
@@ -891,24 +886,17 @@ def batched_summa3d(
     overlaps device compute. A nonzero flag drops that batch to the
     synchronous retry loop (capacities ×2 per attempt — selection first,
     multiply second). ``pipelined=False`` is the serial schedule: one host
-    sync per batch.
+    sync per batch. Consumers are always invoked in batch order.
 
-    ``exec_spec.binned`` switches the sparse local multiply to the k-binned
-    paired kernel: "auto" uses it when the symbolic bin plan strictly
-    reduces pairing work (and the semiring is plus_times); True forces it;
-    False pins ESC. Consumers are always invoked in batch order.
-
-    ``spec.local_path`` is the plan-driven 3-way dispatch over ESC /
-    k-binned / hash-accumulator local multiplies: "auto" (default) lets the
-    plan pick — hash when the compression factor clears
-    ``HASH_CF_THRESHOLD`` (any semiring; the plan then budgets
-    O(nnz_out·load_factor) resident bytes, so high-cf multiplies batch
-    less), else the existing binned-vs-ESC choice; "hash"/"binned"/"esc"
-    force a path. An explicit ``binned`` override (True/False) pins the
-    classic two-way dispatch — back-compat for callers that predate the
-    hash path. One ``local_path`` decision is made per plan (not per batch)
-    so iterated runs keep ONE executable per path; ``floors.hash_caps``
-    keeps its static caps monotone across iterations.
+    ``spec.local_path`` is the plan-driven dispatch over the ESC and
+    hash-accumulator local multiplies (both any semiring): "auto" (default)
+    lets the plan pick — hash when the compression factor clears
+    ``HASH_CF_THRESHOLD`` and its packed key fits i32 (the plan then
+    budgets O(nnz_out·load_factor) resident bytes, so high-cf multiplies
+    batch less), else ESC; "hash"/"esc" force a path. One ``local_path``
+    decision is made per plan (not per batch) so iterated runs keep ONE
+    executable per path; ``floors.hash_caps`` keeps its static caps
+    monotone across iterations.
 
     ``exec_spec.degrade`` (default on) bounds the retry ladder at a
     per-process memory ceiling: when doubling the multiply caps would exceed
@@ -947,14 +935,11 @@ def batched_summa3d(
     local_path = spec.local_path
     pipelined = ex.pipelined
     max_retries, degrade = ex.max_retries, ex.degrade
-    sorted_merge, binned = ex.sorted_merge, ex.binned
-    kbin_caps_floor, caps_pow2 = floors.kbin_caps, floors.caps_pow2
-    assert local_path in ("auto", "esc", "binned", "hash"), local_path
-    # the plan only budgets the hash path when the driver could dispatch it:
-    # an explicit binned override pins the classic O(flops) budget.
-    plan_local_path = local_path
-    if local_path == "auto" and (binned != "auto" or path != "sparse"):
-        plan_local_path = "esc"
+    sorted_merge = ex.sorted_merge
+    assert local_path in ("auto", "esc", "hash"), local_path
+    # the dense path plans its own footprint; the plan only budgets the
+    # hash path when the driver could dispatch it
+    plan_local_path = "dense" if path == "dense" else local_path
     plan = plan_batches(
         a, b, grid, per_process_memory,
         spec=spec.replace(local_path=plan_local_path), floors=floors,
@@ -963,43 +948,6 @@ def batched_summa3d(
     n_cols = b.shape[1]
 
     use_hash = path == "sparse" and plan.local_path == "hash"
-    if use_hash:
-        use_binned = False
-    elif local_path == "binned":
-        use_binned = path == "sparse"
-    elif local_path == "esc":
-        use_binned = False
-    elif binned == "auto":
-        use_binned = (
-            path == "sparse"
-            and semiring.name == "plus_times"
-            and plan.binned_profitable
-        )
-    else:
-        use_binned = bool(binned) and path == "sparse"
-    if use_binned and semiring.name != "plus_times":
-        raise ValueError(
-            f"k-binned local multiply requires plus_times, got {semiring.name}"
-        )
-    kb = (
-        BinnedCaps(plan.kbin.num_bins, plan.kbin.bin_cap_a, plan.kbin.bin_cap_b)
-        if use_binned else None
-    )
-    if kb is not None and caps_pow2:
-        # same quantization as BatchCaps, for the same jit-cache reason
-        kb = BinnedCaps(
-            kb.num_bins, _rup_pow2(kb.bin_cap_a), _rup_pow2(kb.bin_cap_b)
-        )
-    if kb is not None and kbin_caps_floor is not None:
-        assert kb.num_bins == kbin_caps_floor.num_bins, (
-            "kbin_caps_floor requires a pinned bin count (kbin_candidates)"
-        )
-        kb = BinnedCaps(
-            kb.num_bins,
-            max(kb.bin_cap_a, kbin_caps_floor.bin_cap_a),
-            max(kb.bin_cap_b, kbin_caps_floor.bin_cap_b),
-        )
-    bin_of_k = jnp.asarray(plan.kbin.bin_of_k) if use_binned else None
     hc = plan.hash_caps if use_hash else None
     if use_hash:
         assert hc is not None, "hash dispatch requires planned HashCaps"
@@ -1026,13 +974,13 @@ def batched_summa3d(
     ladder_ceiling = max(per_process_memory, _footprint(caps, sel_cap, hc))
 
     def dispatch(
-        bi: int, caps_: BatchCaps, sel_cap_: int, kb_, hc_, mask_cap_: int
+        bi: int, caps_: BatchCaps, sel_cap_: int, hc_, mask_cap_: int
     ):
         """Async-dispatch one fused batch step; nothing blocks here."""
         return _fused_jit(
-            a, b, jnp.int32(bi), bin_of_k, mask, grid=grid, num_batches=nb,
+            a, b, jnp.int32(bi), mask, grid=grid, num_batches=nb,
             sel_cap=sel_cap_, caps=caps_, semiring=semiring,
-            sorted_merge=sorted_merge, path=path, kbin=kb_, hashc=hc_,
+            sorted_merge=sorted_merge, path=path, hashc=hc_,
             mask_cap=mask_cap_, mask_complement=mask_complement,
         )
 
@@ -1042,11 +990,11 @@ def batched_summa3d(
     # iteration. Dispatch defaults stay at the planned values within this
     # run: the pipelined and serial schedules must remain batch-identical
     # (each batch's retry ladder grows from the same base).
-    used = {"caps": caps, "sel": sel_cap, "kb": kb, "hashc": hc,
+    used = {"caps": caps, "sel": sel_cap, "hashc": hc,
             "mask": mask_cap}
 
     def grow(
-        o: np.ndarray, caps_: BatchCaps, sel_cap_: int, kb_, hc_,
+        o: np.ndarray, caps_: BatchCaps, sel_cap_: int, hc_,
         mask_cap_: int, record: bool = True,
     ):
         """Next capacity plan after an overflow: selection first (a truncated
@@ -1072,11 +1020,10 @@ def batched_summa3d(
                     f"{ladder_ceiling}-byte ceiling"
                 )
             caps_, hc_ = cand_caps, cand_hc
-            kb_ = kb_.doubled() if kb_ is not None else None
             if mask is not None:
                 mask_cap_ = min(mask_cap_ * 2, mask.cap)
         if not record:
-            return caps_, sel_cap_, kb_, hc_, mask_cap_
+            return caps_, sel_cap_, hc_, mask_cap_
         used["sel"] = max(used["sel"], sel_cap_)
         used["mask"] = max(used["mask"], mask_cap_)
         used["caps"] = BatchCaps(*(
@@ -1084,12 +1031,6 @@ def batched_summa3d(
                 dataclasses.astuple(used["caps"]), dataclasses.astuple(caps_)
             )
         ))
-        if kb_ is not None:
-            used["kb"] = BinnedCaps(
-                kb_.num_bins,
-                max(used["kb"].bin_cap_a, kb_.bin_cap_a),
-                max(used["kb"].bin_cap_b, kb_.bin_cap_b),
-            )
         if hc_ is not None:
             used["hashc"] = HashCaps(
                 table_cap=max(used["hashc"].table_cap, hc_.table_cap),
@@ -1097,23 +1038,23 @@ def batched_summa3d(
                 num_chunks=max(used["hashc"].num_chunks, hc_.num_chunks),
                 max_probes=max(used["hashc"].max_probes, hc_.max_probes),
             )
-        return caps_, sel_cap_, kb_, hc_, mask_cap_
+        return caps_, sel_cap_, hc_, mask_cap_
 
     def run_batch_sync(
-        bi: int, caps_: BatchCaps, sel_cap_: int, kb_, hc_, mask_cap_: int,
+        bi: int, caps_: BatchCaps, sel_cap_: int, hc_, mask_cap_: int,
         dispatch_fn=None, record: bool = True,
     ):
         """The kept, tested synchronous retry loop (§IV-A robustness)."""
         nonlocal retries
         dispatch_fn = dispatch_fn or dispatch
         for _ in range(max_retries + 1):
-            c_batch, ovf = dispatch_fn(bi, caps_, sel_cap_, kb_, hc_, mask_cap_)
+            c_batch, ovf = dispatch_fn(bi, caps_, sel_cap_, hc_, mask_cap_)
             o = np.asarray(ovf)
             if not o.any():
                 return c_batch
             retries += 1
-            caps_, sel_cap_, kb_, hc_, mask_cap_ = grow(
-                o, caps_, sel_cap_, kb_, hc_, mask_cap_, record=record
+            caps_, sel_cap_, hc_, mask_cap_ = grow(
+                o, caps_, sel_cap_, hc_, mask_cap_, record=record
             )
         raise RuntimeError(
             f"batch {bi}: capacity overflow persisted after {max_retries} retries"
@@ -1125,17 +1066,18 @@ def batched_summa3d(
         construction), then merge back to the original batch extent. Split
         factor doubles while a sub-batch still hits the ceiling; a split
         finer than the column structure allows surfaces as RuntimeError."""
-        forced = "hash" if use_hash else ("binned" if use_binned else "esc")
+        forced = "dense" if path == "dense" else (
+            "hash" if use_hash else "esc"
+        )
         d = 2
         while True:
             try:
-                # a fresh sub-plan: caller floors and bin pins do not apply
-                # (sub-batch caps live in their own static-signature space)
+                # a fresh sub-plan: caller floors do not apply (sub-batch
+                # caps live in their own static-signature space)
                 sub = plan_batches(
                     a, b, grid, per_process_memory,
                     spec=spec.replace(
                         local_path=forced, force_num_batches=nb * d,
-                        kbin_candidates=None,
                     ),
                 )
             except MemoryError as e:
@@ -1149,27 +1091,21 @@ def batched_summa3d(
                 d = nb_f // nb + 1
                 continue
             d_eff = nb_f // nb
-            sub_kb = (
-                BinnedCaps(sub.kbin.num_bins, sub.kbin.bin_cap_a,
-                           sub.kbin.bin_cap_b)
-                if use_binned else None
-            )
-            sub_bin = jnp.asarray(sub.kbin.bin_of_k) if use_binned else None
             sub_hc = sub.hash_caps if use_hash else None
 
-            def sub_dispatch(sj, caps_, sel_cap_, kb_, hc_, mask_cap_):
+            def sub_dispatch(sj, caps_, sel_cap_, hc_, mask_cap_):
                 return _fused_jit(
-                    a, b, jnp.int32(sj), sub_bin, mask, grid=grid,
+                    a, b, jnp.int32(sj), mask, grid=grid,
                     num_batches=nb_f, sel_cap=sel_cap_, caps=caps_,
                     semiring=semiring, sorted_merge=sorted_merge, path=path,
-                    kbin=kb_, hashc=hc_, mask_cap=mask_cap_,
+                    hashc=hc_, mask_cap=mask_cap_,
                     mask_complement=mask_complement,
                 )
 
             try:
                 parts = [
                     run_batch_sync(
-                        d_eff * bi + q, sub.caps, sub.sel_cap, sub_kb, sub_hc,
+                        d_eff * bi + q, sub.caps, sub.sel_cap, sub_hc,
                         sub.mask_sel_cap, dispatch_fn=sub_dispatch,
                         record=False,
                     )
@@ -1185,10 +1121,10 @@ def batched_summa3d(
             return _merge_split_batches(tuple(parts), grid)
 
     def run_batch_guarded(
-        bi: int, caps_: BatchCaps, sel_cap_: int, kb_, hc_, mask_cap_: int
+        bi: int, caps_: BatchCaps, sel_cap_: int, hc_, mask_cap_: int
     ):
         try:
-            return run_batch_sync(bi, caps_, sel_cap_, kb_, hc_, mask_cap_)
+            return run_batch_sync(bi, caps_, sel_cap_, hc_, mask_cap_)
         except _LadderBlocked:
             return run_batch_degraded(bi)
 
@@ -1208,7 +1144,7 @@ def batched_summa3d(
             # product — recompute synchronously and re-run the hook on it
             try:
                 c_batch = run_batch_sync(
-                    bi, *grow(o, caps, sel_cap, kb, hc, mask_cap)
+                    bi, *grow(o, caps, sel_cap, hc, mask_cap)
                 )
             except _LadderBlocked:
                 c_batch = run_batch_degraded(bi)
@@ -1228,7 +1164,7 @@ def batched_summa3d(
     if not pipelined:
         for bi in range(nb):
             c_batch = post(
-                bi, run_batch_guarded(bi, caps, sel_cap, kb, hc, mask_cap)
+                bi, run_batch_guarded(bi, caps, sel_cap, hc, mask_cap)
             )
             consumed.append(consumer(bi, c_batch, _col_map(bi)))
     else:
@@ -1237,7 +1173,7 @@ def batched_summa3d(
 
         window = LookaheadWindow.from_exec(ex, finish)
         for bi in range(nb):
-            c_batch, ovf = dispatch(bi, caps, sel_cap, kb, hc, mask_cap)
+            c_batch, ovf = dispatch(bi, caps, sel_cap, hc, mask_cap)
             window.push(bi, post(bi, c_batch), ovf)
         window.drain()
     # report the capacities actually used (incl. any retry growth) so
@@ -1246,14 +1182,16 @@ def batched_summa3d(
         plan, caps=used["caps"], sel_cap=used["sel"],
         mask_sel_cap=used["mask"], hash_caps=used["hashc"],
     )
-    executed = "hash" if use_hash else ("binned" if use_binned else "esc")
+    executed = "dense" if path == "dense" else (
+        "hash" if use_hash else "esc"
+    )
     report = RunReport(
         retries=retries, sel_retries=rep["sel_retries"],
         replans=rep["replans"], ladder_blocked=rep["ladder_blocked"],
         degraded_batches=tuple(rep["degraded"]),
     )
     return BatchedResult(
-        plan=plan, num_retries=retries, consumed=consumed, binned=use_binned,
-        binned_caps=used["kb"], local_path=executed, hash_caps=used["hashc"],
+        plan=plan, num_retries=retries, consumed=consumed,
+        local_path=executed, hash_caps=used["hashc"],
         report=report,
     )

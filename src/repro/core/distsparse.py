@@ -34,9 +34,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 
-from ..compat import shard_map
 from .grid import COL_AX, LAYER_AX, ROW_AX, Grid
 from .sparse import SparseCOO, from_numpy_coo
 

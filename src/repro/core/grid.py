@@ -15,9 +15,7 @@ import math
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
-
-from ..compat import AxisType, make_mesh as _make_mesh
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 ROW_AX, COL_AX, LAYER_AX = "gr", "gc", "gl"
 
@@ -58,7 +56,7 @@ def make_grid(pr: int, pc: int, l: int, devices: Optional[Sequence] = None) -> G
     import numpy as np
 
     dev_array = np.asarray(devices[:ndev]).reshape(pr, pc, l)
-    mesh = _make_mesh(
+    mesh = Mesh(
         dev_array,
         (ROW_AX, COL_AX, LAYER_AX),
         axis_types=(AxisType.Auto,) * 3,
@@ -92,7 +90,7 @@ def grid_from_mesh(
         dev = mesh.devices.transpose(perm)
     else:
         dev = mesh.devices.transpose(perm)[..., None]
-    new_mesh = _make_mesh(
+    new_mesh = Mesh(
         dev, (ROW_AX, COL_AX, LAYER_AX), axis_types=(AxisType.Auto,) * 3
     )
     return Grid(new_mesh, pr, pc, l)

@@ -12,10 +12,10 @@ insight (unsorted accumulation into a direct-addressed structure) as:
   * ``spgemm_esc`` — expand–sort–compress, keeping *inputs unsorted* and only
     producing sorted output at the final compress, mirroring the paper's
     sortedness observation. Sorting maps to TPU-friendly sorting networks.
-  * ``spgemm_kbinned`` — k-binned paired multiply (``kernels/spgemm_binned``):
-    counting-sort both operands by contraction range, pair only matching bins,
-    accumulate dense, sparsify. Same (C, overflow) contract as ``spgemm_esc``;
-    the batch plan picks between them per workload.
+  * ``spgemm_hash`` — hash-accumulator multiply (``kernels/spgemm_hash``):
+    partial products enumerated in chunks and inserted into an
+    open-addressing table, O(output) scratch. Same (C, overflow) contract as
+    ``spgemm_esc``; the batch plan picks between them per workload.
   * ``spmm`` — sparse × dense (used by MoE dispatch and the dense-acc path).
   * ``local_symbolic`` — Alg. 3's LocalSymbolic: flops (upper bound) and exact
     output nnz of a local product, without forming values.
@@ -31,7 +31,6 @@ import jax.numpy as jnp
 
 from . import semiring as sr
 from . import sortkeys
-from . import sparse as sparse_mod
 from .sparse import SparseCOO
 
 Array = jnp.ndarray
@@ -239,8 +238,6 @@ def spgemm_hash(
     mask_keys: Array = None,
     mask_complement: bool = False,
     max_probes: int = 32,
-    use_pallas: bool = None,
-    interpret: bool = None,
 ) -> Tuple[SparseCOO, Array]:
     """Sparse × sparse → sparse via a hash accumulator — O(output) scratch.
 
@@ -271,10 +268,6 @@ def spgemm_hash(
     assert k == k2
     assert table_cap >= 8 and table_cap & (table_cap - 1) == 0, table_cap
     assert sortkeys.fits_i32(m, n), (m, n)
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     a_csc = a if a_is_colsorted else a.sort_colmajor()
     bt = b.transpose()  # entries (j, k): rows=j, cols=k
@@ -316,7 +309,7 @@ def spgemm_hash(
             valid = valid & (~hit if mask_complement else hit)
         tk, tv, drop = hashkern.hash_insert(
             tk, tv, key, vals, valid, add_kind=add_kind,
-            max_probes=max_probes, use_pallas=use_pallas, interpret=interpret,
+            max_probes=max_probes,
         )
         return tk, tv, dropped + drop
 
@@ -337,73 +330,13 @@ def spgemm_hash(
     return c_out, ovf_out + flop_overflow + dropped
 
 
-def spgemm_kbinned(
-    a: SparseCOO,
-    b: SparseCOO,
-    out_cap: int,
-    num_bins: int,
-    bin_cap_a: int,
-    bin_cap_b: int,
-    bin_of_k: Array = None,
-    semiring: sr.Semiring = sr.PLUS_TIMES,
-    mask: SparseCOO = None,
-    mask_complement: bool = False,
-) -> Tuple[SparseCOO, Array]:
-    """Sparse × sparse → sparse via the k-binned paired kernel.
-
-    Both operands are counting-sorted into ``num_bins`` contraction ranges
-    (``bin_of_k`` — a monotone map from ``symbolic.plan_k_bins`` — absorbs
-    skewed-k distributions) and only matching bins are paired:
-    O(Σ_g capA_g×capB_g) pairings instead of O(capA×capB). The paired
-    accumulation lands in a dense (m, n) block (narrow under batching), which
-    is then sparsified to ``out_cap`` entries, row-major sorted — the same
-    output contract as ``spgemm_esc``, so the two are interchangeable behind
-    the batch plan's switch.
-
-    Requires the plus_times semiring (the pairing kernel accumulates with
-    + and ×). Returns (C, overflow) where overflow counts both bin-capacity
-    and ``out_cap`` violations (§IV-A retry discipline).
-
-    ``mask`` (a SparseCOO over the output space) applies the masked-SpGEMM
-    filter on the dense accumulator before sparsification — the dense-path
-    twin of ``spgemm_esc``'s packed-key intersect, with the same
-    strict/complement semantics — so ``out_cap`` only pays for survivors.
-    """
-    from ..kernels.spgemm_binned import spgemm_binned_dense
-
-    assert semiring.name == "plus_times", (
-        f"k-binned paired multiply requires plus_times, got {semiring.name}"
-    )
-    m, k = a.shape
-    k2, n = b.shape
-    assert k == k2, (a.shape, b.shape)
-    # gathered operands declare every slot live and rely on sentinel-k
-    # padding — mask on the contraction index, not just nnz
-    a_valid = a.valid_mask() & (a.cols < k)
-    b_valid = b.valid_mask() & (b.rows < k)
-    av = jnp.where(a_valid, a.vals, 0)
-    bv = jnp.where(b_valid, b.vals, 0)
-    on_tpu = jax.default_backend() == "tpu"
-    dense, ovf_bin = spgemm_binned_dense(
-        a.rows, a.cols, av, a_valid, b.rows, b.cols, bv, b_valid,
-        m, n, k, num_bins, bin_cap_a, bin_cap_b, bin_map=bin_of_k,
-        use_pallas=on_tpu, interpret=not on_tpu,
-    )
-    if mask is not None:
-        dense = jnp.where(mask_indicator(mask, mask_complement), dense, 0.0)
-    # the pairing kernel accumulates f32; restore the input dtype so the
-    # binned and ESC paths stay interchangeable behind the plan switch
-    c, ovf_out = sparse_mod.from_dense_overflow(dense.astype(a.dtype), out_cap)
-    return c, ovf_bin + ovf_out
-
-
 def mask_indicator(mask: SparseCOO, complement: bool = False) -> Array:
     """bool (m, n): mask membership as a dense indicator (sentinel-safe).
 
     The dense-accumulator counterpart of the packed-key mask intersect:
     scatter a presence bit per mask entry, flip for the complement mode.
-    Used by the k-binned local multiply and the dense SUMMA path, where the
-    product already lives in a dense block.
+    Used by the dense SUMMA path, where the product already lives in a
+    dense block.
     """
     m, n = mask.shape
     ind = (
@@ -427,11 +360,13 @@ def merge_sparse(
 
       * ``assume_sorted=False`` — inputs unsorted; one packed-key coalesce
         over the concatenated entry lists (bucket scan or single-key sort).
-      * ``assume_sorted=True`` — every part is already row-major sorted (true
-        for ESC outputs and their column-split pieces, i.e. exactly what
-        Merge-Fiber receives), so the parts are *merged*, not re-sorted: a
-        segmented k-way merge-path over packed keys (ceil(log2 l) rank/scatter
-        rounds), then a linear compress. No sort anywhere.
+      * ``assume_sorted=True`` — every part is already row-major sorted with
+        distinct coordinates (true for ESC and hash outputs and their
+        column-split pieces, i.e. exactly what Merge-Fiber receives), so the
+        parts are *merged*, not re-sorted: a segmented k-way merge-path over
+        packed keys (ceil(log2 l) rank/scatter rounds), then a linear
+        compress. No sort anywhere. A single such part is already merged
+        and only changes capacity.
 
     Returns (merged, overflow).
     """
@@ -439,6 +374,9 @@ def merge_sparse(
     for x in parts:
         assert x.shape == shape
     m, n = shape
+    if assume_sorted and len(parts) == 1:
+        (x,) = parts
+        return x.with_capacity(out_cap), jnp.maximum(x.nnz - out_cap, 0)
     if assume_sorted and engine != "lexsort" and sortkeys.fits_i32(m, n):
         # padding carries (m, n) sentinels == max key, so each part's packed
         # key array is ascending end-to-end and merges keep sentinels last

@@ -166,8 +166,8 @@ class SparseCOO:
         ``select_col_block`` scans.
 
         Entry e goes to piece ``col // (n/num_pieces)``; its slot within the
-        piece is its rank among same-piece entries (a cumulative one-hot
-        count), so the original entry order is preserved per piece — a
+        piece is its rank among same-piece entries (a running count per
+        piece), so the original entry order is preserved per piece — a
         row-major-sorted input yields row-major-sorted pieces, exactly the
         invariant the segmented Merge-Fiber relies on. Columns are remapped
         to [0, n/num_pieces).
@@ -182,14 +182,16 @@ class SparseCOO:
         piece_w = n // num_pieces
         valid = self.valid_mask()
         piece = jnp.where(valid, self.cols // piece_w, num_pieces)
-        onehot = (
-            piece[:, None] == jnp.arange(num_pieces, dtype=jnp.int32)[None, :]
-        ).astype(jnp.int32)  # (cap, num_pieces)
-        rank_excl = jnp.cumsum(onehot, axis=0) - onehot  # rank within piece
-        rank = jnp.take_along_axis(
-            rank_excl, jnp.clip(piece, 0, num_pieces - 1)[:, None], axis=1
-        )[:, 0]
-        counts = jnp.sum(onehot, axis=0)  # (num_pieces,)
+        # rank within piece: one 1-D running count per piece. A (cap,
+        # num_pieces) one-hot would be laid out with its narrow minor dim
+        # padded to 128 lanes on the TPU — 128/num_pieces x the memory.
+        rank = jnp.zeros_like(piece)
+        counts = []
+        for p in range(num_pieces):
+            hit = (piece == p).astype(jnp.int32)
+            rank = jnp.where(piece == p, jnp.cumsum(hit) - hit, rank)
+            counts.append(jnp.sum(hit))
+        counts = jnp.stack(counts)  # (num_pieces,)
         ok = valid & (piece < num_pieces) & (rank < piece_cap)
         flat = num_pieces * piece_cap
         dest = jnp.where(ok, piece * piece_cap + rank, flat)  # discard bucket

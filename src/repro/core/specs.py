@@ -1,16 +1,16 @@
 """Frozen planning/execution specs — the unified knob surface of the driver.
 
 ``plan_batches`` grew 15 keyword knobs and ``batched_summa3d`` 22 as the
-paper's features landed (masked planning, k-binning, the hash path, the
-retry ladder, iterated-multiply cap pinning). This module collapses them
+paper's features landed (masked planning, the hash path, the retry
+ladder, iterated-multiply cap pinning). This module collapses them
 into three frozen dataclasses so every caller — MCL, APSP, the serving
 engine, the autotuner — passes the SAME objects instead of hand-threading
 floor kwargs:
 
-  * ``PlanSpec``   — WHAT to plan: mask, local path, slack, reserved bytes,
-    k-bin candidates. Pure policy; two calls with the same spec and operands
+  * ``PlanSpec``   — WHAT to plan: mask, local path, slack, reserved bytes.
+    Pure policy; two calls with the same spec and operands
     produce the same ``BatchPlan``.
-  * ``PlanFloors`` — capacity floors carried ACROSS plans: the five
+  * ``PlanFloors`` — capacity floors carried ACROSS plans: the four
     ``*_floor`` knobs plus ``caps_pow2``, with a monotonic ``merged()``
     (elementwise max, like ``RunReport.merged``) so iterated callers pin the
     fused step's static signature by folding each run's used capacities back
@@ -34,26 +34,25 @@ import dataclasses
 import warnings
 from typing import Optional, Tuple
 
-from .summa3d import BatchCaps, BinnedCaps, HashCaps
+from .summa3d import BatchCaps, HashCaps
 
 
 @dataclasses.dataclass(frozen=True)
 class PlanSpec:
     """Planning policy for one multiply (see ``plan_batches``).
 
-    ``local_path`` defaults to "auto" — the plan-driven 3-way dispatch.
+    ``local_path`` defaults to "auto" — the plan-driven ESC/hash dispatch.
     Bare ``plan_batches()`` calls (no spec) keep their historical "esc"
     default; a caller who passes a spec opts into the driver's semantics.
     """
 
     mask: Optional[object] = None  # C-layout DistSparse (§V-B masked plans)
     mask_complement: bool = False
-    local_path: str = "auto"  # "auto" | "esc" | "binned" | "hash"
+    local_path: str = "auto"  # "auto" | "esc" | "hash" (| "dense": driver)
     slack: float = 1.3
     r_bytes: int = 12
     reserved_bytes: int = 0
     force_num_batches: Optional[int] = None
-    kbin_candidates: Optional[Tuple[int, ...]] = None
     # Structure-aware placement (core.placement). ``placement`` means "the
     # operands are ALREADY permuted by this Placement": the driver remaps
     # every consumer-facing column map back to original column space (use
@@ -87,40 +86,21 @@ class PlanFloors:
     own derived value, so floors can only grow capacities, never shrink
     them — which is exactly what keeps the fused step's static signature
     stable (jit-cache hits) as nnz drifts across iterations.
-
-    ``kbin_caps`` doubles as the bin-count pin: when set and the spec
-    leaves ``kbin_candidates`` unset, the planner pins the candidate list
-    to ``(kbin_caps.num_bins,)`` — one field replaces the old
-    ``kbin_candidates`` + ``kbin_caps_floor`` pair every iterated caller
-    hand-threaded.
     """
 
     caps: Optional[BatchCaps] = None
     sel_cap: int = 0
     num_batches: int = 0
-    kbin_caps: Optional[BinnedCaps] = None
     hash_caps: Optional[HashCaps] = None
     caps_pow2: bool = False
 
     def merged(self, other: "PlanFloors") -> "PlanFloors":
         """Monotonic fold (like ``RunReport.merged``): elementwise max, so
-        ``a.merged(b)`` dominates both a and b. Mixing floors with different
-        pinned bin counts is a caller bug (two incompatible static
-        signatures) and raises."""
-        if (
-            self.kbin_caps is not None
-            and other.kbin_caps is not None
-            and self.kbin_caps.num_bins != other.kbin_caps.num_bins
-        ):
-            raise ValueError(
-                f"cannot merge floors with different pinned bin counts "
-                f"({self.kbin_caps.num_bins} vs {other.kbin_caps.num_bins})"
-            )
+        ``a.merged(b)`` dominates both a and b."""
         return PlanFloors(
             caps=_emax(self.caps, other.caps, BatchCaps),
             sel_cap=max(self.sel_cap, other.sel_cap),
             num_batches=max(self.num_batches, other.num_batches),
-            kbin_caps=_emax(self.kbin_caps, other.kbin_caps, BinnedCaps),
             hash_caps=_emax(self.hash_caps, other.hash_caps, HashCaps),
             caps_pow2=self.caps_pow2 or other.caps_pow2,
         )
@@ -137,7 +117,6 @@ class PlanFloors:
             "caps": enc(self.caps),
             "sel_cap": int(self.sel_cap),
             "num_batches": int(self.num_batches),
-            "kbin_caps": enc(self.kbin_caps),
             "hash_caps": enc(self.hash_caps),
             "caps_pow2": bool(self.caps_pow2),
         }
@@ -151,7 +130,6 @@ class PlanFloors:
             caps=dec(d.get("caps"), BatchCaps),
             sel_cap=int(d.get("sel_cap", 0)),
             num_batches=int(d.get("num_batches", 0)),
-            kbin_caps=dec(d.get("kbin_caps"), BinnedCaps),
             hash_caps=dec(d.get("hash_caps"), HashCaps),
             caps_pow2=bool(d.get("caps_pow2", False)),
         )
@@ -166,7 +144,6 @@ class ExecSpec:
     max_retries: int = 4
     degrade: bool = True
     sorted_merge: bool = True
-    binned: object = "auto"  # legacy 2-way override; prefer PlanSpec.local_path
 
     def replace(self, **kw) -> "ExecSpec":
         return dataclasses.replace(self, **kw)
@@ -181,13 +158,11 @@ _PLAN_KEYS = {
     "r_bytes": "r_bytes",
     "reserved_bytes": "reserved_bytes",
     "force_num_batches": "force_num_batches",
-    "kbin_candidates": "kbin_candidates",
 }
 _FLOOR_KEYS = {
     "caps_floor": "caps",
     "sel_cap_floor": "sel_cap",
     "num_batches_floor": "num_batches",
-    "kbin_caps_floor": "kbin_caps",
     "hash_caps_floor": "hash_caps",
     "caps_pow2": "caps_pow2",
 }
@@ -197,7 +172,6 @@ _EXEC_KEYS = {
     "max_retries": "max_retries",
     "degrade": "degrade",
     "sorted_merge": "sorted_merge",
-    "binned": "binned",
 }
 
 

@@ -14,11 +14,9 @@ One shard_map'd step computes a full 3D multiply for one batch:
      paper's "merge once after all stages" observation (§III-A).
   2. Local-Multiply (Alg. 1 line 7): dense-accumulator path (spmm into a
      dense D tile — identity-hash accumulator) or sparse path with a
-     plan-driven switch between ESC (expand-sort-compress, any semiring) and
-     the k-binned paired kernel (``local_spgemm.spgemm_kbinned``: pair only
-     matching contraction bins — O(Σ_g capA_g×capB_g) pairings instead of
-     O(capA×capB); the symbolic step emits the bin plan from the count
-     vectors it already moves).
+     plan-driven switch between ESC (expand-sort-compress) and the
+     hash-accumulator multiply (``local_spgemm.spgemm_hash``: O(output)
+     scratch instead of O(flops)), both for any semiring.
   3. AllToAll-Fiber + Merge-Fiber (Alg. 2 lines 4-6): dense path lowers the
      pair to ONE ``lax.psum_scatter`` over the layer axis (all-to-all + local
      add is exactly reduce-scatter); sparse path runs ColSplit as a single
@@ -52,11 +50,10 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 
 from . import semiring as sr
 from . import sortkeys
-from ..compat import axis_size, shard_map
 from .distsparse import DistSparse, dist_spec
 from .grid import COL_AX, LAYER_AX, ROW_AX, Grid
 from .local_spgemm import (
@@ -64,7 +61,6 @@ from .local_spgemm import (
     merge_sparse,
     spgemm_esc,
     spgemm_hash,
-    spgemm_kbinned,
     spmm,
 )
 from .sparse import SparseCOO, concat as sparse_concat
@@ -92,27 +88,6 @@ class BatchCaps:
         return BatchCaps(
             flops_cap=self.flops_cap * 2, d_cap=self.d_cap * 2,
             piece_cap=self.piece_cap * 2, c_cap=self.c_cap * 2,
-        )
-
-
-@dataclasses.dataclass(frozen=True)
-class BinnedCaps:
-    """Static parameters of the k-binned local multiply (hashable — jit-static).
-
-    The dynamic part of the bin plan (the monotone ``bin_of_k`` map over the
-    per-layer contraction space) travels as a replicated traced array so one
-    executable serves any bin boundary choice.
-    """
-
-    num_bins: int
-    bin_cap_a: int  # gathered-A entries per bin, per process
-    bin_cap_b: int  # gathered-B entries per bin, per process
-
-    def doubled(self) -> "BinnedCaps":
-        return BinnedCaps(
-            num_bins=self.num_bins,
-            bin_cap_a=self.bin_cap_a * 2,
-            bin_cap_b=self.bin_cap_b * 2,
         )
 
 
@@ -160,7 +135,7 @@ def _gather_A(a: SparseCOO) -> SparseCOO:
     to the per-layer contraction space (stage s occupies [s*wl, (s+1)*wl))."""
     tm, wl = a.shape
     s = lax.axis_index(COL_AX)
-    pc = axis_size(COL_AX)
+    pc = lax.axis_size(COL_AX)
     k_tot = pc * wl
     valid = a.valid_mask()
     rows = jnp.where(valid, a.rows, tm)
@@ -179,7 +154,7 @@ def _gather_B(b: SparseCOO) -> SparseCOO:
     to the per-layer contraction space (stage i occupies [i*wl, (i+1)*wl))."""
     wl, tn = b.shape
     i = lax.axis_index(ROW_AX)
-    pr = axis_size(ROW_AX)
+    pr = lax.axis_size(ROW_AX)
     k_tot = pr * wl
     valid = b.valid_mask()
     rows = jnp.where(valid, b.rows + i * wl, k_tot)
@@ -318,7 +293,7 @@ def summa3d_dense_step(
 
 
 # ---------------------------------------------------------------------------
-# Sparse (ESC / k-binned) path
+# Sparse (ESC / hash) path
 # ---------------------------------------------------------------------------
 def _pmax_grid(x: Array) -> Array:
     return lax.pmax(lax.pmax(lax.pmax(x, ROW_AX), COL_AX), LAYER_AX)
@@ -331,74 +306,70 @@ def _psum_grid(x: Array) -> Array:
 def _sparse_tile_body(
     a_loc: SparseCOO, b_loc: SparseCOO, l: int, caps: BatchCaps,
     semiring: sr.Semiring, sorted_merge: bool,
-    kbin: "BinnedCaps" = None, bin_of_k: Array = None,
     mask: SparseCOO = None, mask_complement: bool = False,
     hashc: "HashCaps" = None,
 ) -> Tuple[SparseCOO, Array]:
     """Per-device sparse pipeline (inside shard_map): gather → local multiply
     → partitioned ColSplit → AllToAll-Fiber → Merge-Fiber.
 
-    ``kbin``/``hashc`` select the local multiply: None/None runs ESC (any
-    semiring); a ``BinnedCaps`` runs the k-binned paired kernel (plus_times
-    only), pairing O(Σ_g capA_g×capB_g) instead of O(capA×capB); a
-    ``HashCaps`` runs the hash-accumulator multiply (any semiring),
-    O(table + chunk) scratch instead of O(flops) — the plan-driven 3-way
-    switch the symbolic step emits. All produce a row-major-sorted D tile,
-    so the downstream split/merge invariants are identical.
+    ``hashc`` selects the local multiply: None runs ESC; a ``HashCaps`` runs
+    the hash-accumulator multiply, O(table + chunk) scratch instead of
+    O(flops) — the plan-driven switch the symbolic step emits. Both take any
+    semiring and produce a row-major-sorted D tile, so the downstream
+    split/merge invariants are identical.
 
     ``mask`` (a SparseCOO over the D tile's (tm, tn_b) output space) runs the
-    masked/filtered formulation: ESC intersects the expanded products'
-    packed keys against the mask's sorted keys before the compress, the
-    binned path filters its dense accumulator — either way only surviving
-    coordinates consume ``caps.d_cap`` and everything downstream
+    masked/filtered formulation: both paths intersect the partial products'
+    packed keys against the mask's sorted keys before they are stored, so
+    only surviving coordinates consume ``caps.d_cap`` and everything downstream
     (ColSplit pieces, the fiber exchange, Merge-Fiber) carries survivors
     only, which is where the masked memory/traffic win lives.
     """
-    assert kbin is None or hashc is None, "kbin and hashc are exclusive"
     tm_a, _ = a_loc.shape
     _, tn_b = b_loc.shape
     piece_w = tn_b // l
     a_cat = _gather_A(a_loc)
     b_cat = _gather_B(b_loc)
-    if kbin is None:
-        mkeys = None
-        if mask is not None:
-            mkeys = sortkeys.sorted_mask_keys(
-                mask.rows, mask.cols, mask.valid_mask(), (tm_a, tn_b)
-            )
-        if hashc is not None:
-            d_tile, ovf_mul = spgemm_hash(
-                a_cat, b_cat, out_cap=caps.d_cap,
-                table_cap=hashc.table_cap, chunk_cap=hashc.chunk_cap,
-                num_chunks=hashc.num_chunks, semiring=semiring,
-                mask_keys=mkeys, mask_complement=mask_complement,
-                max_probes=hashc.max_probes,
-            )  # (tm, tn_b) sparse, row-major sorted
-        else:
-            d_tile, ovf_mul = spgemm_esc(
-                a_cat, b_cat, out_cap=caps.d_cap, flops_cap=caps.flops_cap,
-                semiring=semiring, mask_keys=mkeys,
-                mask_complement=mask_complement,
-            )  # (tm, tn_b) sparse, row-major sorted
-    else:
-        d_tile, ovf_mul = spgemm_kbinned(
-            a_cat, b_cat, caps.d_cap, kbin.num_bins, kbin.bin_cap_a,
-            kbin.bin_cap_b, bin_of_k=bin_of_k, semiring=semiring,
-            mask=mask, mask_complement=mask_complement,
+    mkeys = None
+    if mask is not None:
+        mkeys = sortkeys.sorted_mask_keys(
+            mask.rows, mask.cols, mask.valid_mask(), (tm_a, tn_b)
         )
-    # ColSplit (Alg. 2 line 4): one partitioned split into all l pieces,
-    # order-preserving (pieces stay row-major sorted), sized by piece_cap
-    pr_, pc_, pv_, pn_, ovf_split = d_tile.split_col_blocks(l, caps.piece_cap)
-    # AllToAll-Fiber (Alg. 2 line 5)
-    pr_ = lax.all_to_all(pr_, LAYER_AX, split_axis=0, concat_axis=0)
-    pc_ = lax.all_to_all(pc_, LAYER_AX, split_axis=0, concat_axis=0)
-    pv_ = lax.all_to_all(pv_, LAYER_AX, split_axis=0, concat_axis=0)
-    pn_ = lax.all_to_all(pn_[:, None], LAYER_AX, split_axis=0, concat_axis=0)[:, 0]
-    # Merge-Fiber (Alg. 2 line 6): sort-free merge of l received pieces
-    parts = [
-        SparseCOO(pr_[k], pc_[k], pv_[k], pn_[k], (tm_a, piece_w))
-        for k in range(l)
-    ]
+    if hashc is not None:
+        d_tile, ovf_mul = spgemm_hash(
+            a_cat, b_cat, out_cap=caps.d_cap,
+            table_cap=hashc.table_cap, chunk_cap=hashc.chunk_cap,
+            num_chunks=hashc.num_chunks, semiring=semiring,
+            mask_keys=mkeys, mask_complement=mask_complement,
+            max_probes=hashc.max_probes,
+        )  # (tm, tn_b) sparse, row-major sorted
+    else:
+        d_tile, ovf_mul = spgemm_esc(
+            a_cat, b_cat, out_cap=caps.d_cap, flops_cap=caps.flops_cap,
+            semiring=semiring, mask_keys=mkeys,
+            mask_complement=mask_complement,
+        )  # (tm, tn_b) sparse, row-major sorted
+    if l == 1:
+        # one fiber layer: the D tile is the only piece and stays here
+        parts, ovf_split = [d_tile], 0
+    else:
+        # ColSplit (Alg. 2 line 4): one partitioned split into all l
+        # pieces, order-preserving (pieces stay row-major sorted)
+        pr_, pc_, pv_, pn_, ovf_split = d_tile.split_col_blocks(
+            l, caps.piece_cap
+        )
+        # AllToAll-Fiber (Alg. 2 line 5)
+        pr_ = lax.all_to_all(pr_, LAYER_AX, split_axis=0, concat_axis=0)
+        pc_ = lax.all_to_all(pc_, LAYER_AX, split_axis=0, concat_axis=0)
+        pv_ = lax.all_to_all(pv_, LAYER_AX, split_axis=0, concat_axis=0)
+        pn_ = lax.all_to_all(
+            pn_[:, None], LAYER_AX, split_axis=0, concat_axis=0
+        )[:, 0]
+        parts = [
+            SparseCOO(pr_[k], pc_[k], pv_[k], pn_[k], (tm_a, piece_w))
+            for k in range(l)
+        ]
+    # Merge-Fiber (Alg. 2 line 6): sort-free merge of the l pieces
     c_tile, ovf_merge = merge_sparse(
         parts, caps.c_cap, semiring, assume_sorted=sorted_merge
     )
@@ -409,8 +380,6 @@ def summa3d_sparse_step(
     a: DistSparse, b_batch: DistSparse, grid: Grid, caps: BatchCaps,
     semiring: sr.Semiring = sr.PLUS_TIMES,
     sorted_merge: bool = True,
-    kbin: BinnedCaps = None,
-    bin_of_k: Array = None,
     hashc: HashCaps = None,
 ) -> Tuple[DistSparse, Array]:
     """One batched-SUMMA3D step, sparse path. Returns (C tiles, overflow).
@@ -423,8 +392,7 @@ def summa3d_sparse_step(
     ``sorted_merge=True`` runs Merge-Fiber as a segmented k-way merge: the l
     received pieces are column splits of row-major-sorted local-multiply
     outputs, so they arrive sorted and only need merging, never re-sorting
-    (§IV-D). ``kbin``/``bin_of_k`` (from the symbolic bin plan) switch the
-    local multiply to the k-binned paired kernel.
+    (§IV-D). ``hashc`` switches the local multiply to the hash accumulator.
     """
     tm_a, _ = a.tile_shape
     _, tn_b = b_batch.tile_shape
@@ -432,11 +400,10 @@ def summa3d_sparse_step(
     assert tn_b % l == 0
     piece_w = tn_b // l
 
-    def step(a_t: DistSparse, b_t: DistSparse, *rest):
-        bok = rest[0] if rest else None
+    def step(a_t: DistSparse, b_t: DistSparse):
         c_tile, ovf = _sparse_tile_body(
             _squeeze_tile(a_t), _squeeze_tile(b_t), l, caps, semiring,
-            sorted_merge, kbin=kbin, bin_of_k=bok, hashc=hashc,
+            sorted_merge, hashc=hashc,
         )
         return (
             c_tile.rows[None, None, None],
@@ -448,17 +415,13 @@ def summa3d_sparse_step(
 
     spec3 = jax.sharding.PartitionSpec(ROW_AX, COL_AX, LAYER_AX)
     spec0 = jax.sharding.PartitionSpec()
-    in_specs = [dist_spec(a, spec3), dist_spec(b_batch, spec3)]
-    args = [a, b_batch]
-    if kbin is not None:
-        in_specs.append(spec0)  # bin map: replicated
-        args.append(bin_of_k)
+    in_specs = (dist_spec(a, spec3), dist_spec(b_batch, spec3))
     fn = shard_map(
-        step, mesh=grid.mesh, in_specs=tuple(in_specs),
+        step, mesh=grid.mesh, in_specs=in_specs,
         out_specs=(spec3, spec3, spec3, spec3, spec0),
         check_vma=False,
     )
-    rows, cols, vals, nnz, ovf = fn(*args)
+    rows, cols, vals, nnz, ovf = fn(a, b_batch)
     m, n = a.shape
     c = DistSparse(
         rows=rows, cols=cols, vals=vals, nnz=nnz,
@@ -477,7 +440,6 @@ def summa3d_fused_step(
     a: DistSparse,
     b_full: DistSparse,
     batch,
-    bin_of_k: Array = None,
     mask: DistSparse = None,
     *,
     grid: Grid,
@@ -487,7 +449,6 @@ def summa3d_fused_step(
     semiring: sr.Semiring = sr.PLUS_TIMES,
     sorted_merge: bool = True,
     path: str = "sparse",
-    kbin: BinnedCaps = None,
     hashc: HashCaps = None,
     mask_cap: int = 0,
     mask_complement: bool = False,
@@ -536,7 +497,6 @@ def summa3d_fused_step(
 
     def step(a_t: DistSparse, b_t: DistSparse, batch_, *rest):
         rest = list(rest)
-        bok = rest.pop(0) if kbin is not None else None
         mask_t = rest.pop(0) if mask is not None else None
         a_loc = _squeeze_tile(a_t)
         b_loc = _squeeze_tile(b_t)
@@ -581,8 +541,7 @@ def summa3d_fused_step(
             return c_tile[None, None, None], jnp.stack([ovf_sel, ovf_mask])
         c_tile, ovf_mul = _sparse_tile_body(
             a_loc, sel, l, caps, semiring, sorted_merge,
-            kbin=kbin, bin_of_k=bok, hashc=hashc,
-            mask=mask_cat, mask_complement=mask_complement,
+            hashc=hashc, mask=mask_cat, mask_complement=mask_complement,
         )
         return (
             c_tile.rows[None, None, None],
@@ -596,9 +555,6 @@ def summa3d_fused_step(
     spec0 = jax.sharding.PartitionSpec()
     in_specs = [dist_spec(a, spec3), dist_spec(b_full, spec3), spec0]
     args = [a, b_full, jnp.int32(batch)]
-    if kbin is not None:
-        in_specs.append(spec0)
-        args.append(bin_of_k)
     if mask is not None:
         in_specs.append(dist_spec(mask, spec3))
         args.append(mask)

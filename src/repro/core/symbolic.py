@@ -31,14 +31,39 @@ Array = jnp.ndarray
 R_BYTES_PAPER = 24
 R_BYTES_DEFAULT = 12
 
+#: r-byte records the compiled ESC step holds per expansion slot
+#: (``flops_cap``): the expanded (row, col, val) triple, the sort's packed
+#: key and permutation, and the compressed D tile and merged C piece it
+#: returns. The v5e ahead-of-time compile of the n = 2^20 protein-network
+#: step (``compiled.memory_analysis()``) holds 32-36 B of temporaries plus
+#: 12 B of output per slot at r = 12: four records.
+ESC_SLOT_RECORDS = 4
+
+#: bytes per element of the dense path's f32 batch buffers
+DENSE_VAL_BYTES = 4
+
+
+def esc_step_bytes(flops_cap: float, r: int = R_BYTES_DEFAULT) -> int:
+    """Bytes the ESC fused step holds for ``flops_cap`` expansion slots."""
+    return int(math.ceil(ESC_SLOT_RECORDS * r * flops_cap))
+
+
+def dense_step_bytes(width: int, a_gather_cap: int, k_dim: int, tm: int) -> int:
+    """Bytes the dense-accumulator step holds for a batch ``width`` columns
+    wide: one gathered row of B and its product per gathered A entry
+    (``a_gather_cap`` × width, twice), the densified B block (``k_dim`` ×
+    width) and the D accumulator plus its reduce-scattered C (``tm`` ×
+    width, twice)."""
+    return DENSE_VAL_BYTES * width * (2 * a_gather_cap + k_dim + 2 * tm)
+
 
 @dataclasses.dataclass(frozen=True)
 class SymbolicCounts:
     """Host-side output of the symbolic pass (all numpy).
 
     Only count *vectors* ever travel (§IV-A, Fig. 8) — the same payload now
-    also carries what the numeric pass needs to size selection buffers and
-    the k-bin plan, so no extra communication round is spent on either.
+    also carries what the numeric pass needs to size selection buffers, so
+    no extra communication round is spent on it.
     ``mask_colcounts`` (masked multiplies only) holds the mask's exact
     per-(tile, local column) entry counts — the §V-B observation that a
     strict mask bounds C's structure, so the batch plan can budget survivors
@@ -54,8 +79,6 @@ class SymbolicCounts:
 
     percol: np.ndarray  # (pr, pc, l, tn_b) flops per local output column
     b_colcounts: np.ndarray  # (pr, pc, l, tn_b) B entries per local column
-    a_kcounts: np.ndarray  # (pr, l, k_tot) per-k counts of gathered A
-    b_kcounts: np.ndarray  # (pc, l, k_tot) per-k counts of gathered B
     mask_colcounts: np.ndarray = None  # (pr, pc, l, wl) mask nnz, or None
 
 
@@ -140,8 +163,6 @@ def host_symbolic_counts(a, b, grid_shape, mask=None) -> SymbolicCounts:
 
     bcc = np.zeros((pr, pc, l, tn_b), np.int64)
     np.add.at(bcc, (b_s, b_j, b_k, b_lc), 1)
-    bkc = np.zeros((pc, l, k_tot), np.int64)
-    np.add.at(bkc, (b_j, b_k, b_q), 1)
 
     # percol[i, j, k, c] = Σ over B entries of (grid col j, layer k, local
     # col c): A's stage-k_idx count in row block i — vectorized as one
@@ -165,7 +186,7 @@ def host_symbolic_counts(a, b, grid_shape, mask=None) -> SymbolicCounts:
         ), 1)
 
     return SymbolicCounts(
-        percol=percol, b_colcounts=bcc, a_kcounts=acc, b_kcounts=bkc,
+        percol=percol, b_colcounts=bcc,
         mask_colcounts=mcc,
     )
 
@@ -261,83 +282,6 @@ def fold_block_cyclic(
     assert w * num_batches * num_layers == n, (n, num_batches, num_layers)
     blocks = percol.reshape(*lead, num_layers, num_batches, w).sum(axis=-1)
     return np.swapaxes(blocks, -1, -2)  # (..., batch, piece)
-
-
-@dataclasses.dataclass(frozen=True)
-class KBinPlan:
-    """Host-side plan for the k-binned paired kernel (all python ints).
-
-    Sizes the static per-bin capacities of ``repro.kernels.spgemm_binned``
-    from the *exact* per-k entry counts (``SparseCOO.col_counts`` of A /
-    ``row_counts`` of B) — the same lightweight count vectors the distributed
-    symbolic step already moves (§IV-A), reused here to bound pairing work.
-    """
-
-    num_bins: int
-    bin_cap_a: int
-    bin_cap_b: int
-    pairings: int  # num_bins * bin_cap_a * bin_cap_b (block-rounded upstream)
-    pairings_unbinned: int  # cap_a * cap_b
-    bin_of_k: np.ndarray  # monotone i32[k_dim] map k -> bin
-
-
-def plan_k_bins(
-    a_col_counts: np.ndarray,
-    b_row_counts: np.ndarray,
-    cap_a: int,
-    cap_b: int,
-    candidates=(1, 2, 4, 8, 16, 32, 64),
-    slack: float = 1.0,
-) -> KBinPlan:
-    """Pick bin boundaries + count minimizing Σ_g capA_g × capB_g (host math).
-
-    For each candidate G two boundary families are scored and the cheaper
-    wins: equal-width k-ranges (bin(k) = k*G // k_dim) and quantile-balanced
-    ranges that cut the *combined* count mass (a+b) into equal slices — the
-    latter is what absorbs skewed-k (R-MAT-like) distributions where a few k
-    values carry most entries. Capacities are maxima over bins of the exact
-    counts (so ``slack=1.0`` cannot overflow). On a distribution concentrated
-    in a single k no boundary helps and the planner falls back to G=1 —
-    binning never hurts correctness, only the pairing bound.
-    """
-    a_cnt = np.asarray(a_col_counts, dtype=np.int64)
-    b_cnt = np.asarray(b_row_counts, dtype=np.int64)
-    k_dim = a_cnt.shape[0]
-    assert b_cnt.shape[0] == k_dim, (a_cnt.shape, b_cnt.shape)
-
-    def score(bin_of_k, g):
-        binned_a = np.zeros(g, np.int64)
-        binned_b = np.zeros(g, np.int64)
-        np.add.at(binned_a, bin_of_k, a_cnt)
-        np.add.at(binned_b, bin_of_k, b_cnt)
-        ca = rup8(max(int(binned_a.max() * slack), 8))
-        cb = rup8(max(int(binned_b.max() * slack), 8))
-        return g * ca * cb, ca, cb
-
-    weight = a_cnt + b_cnt
-    cumw = np.cumsum(weight)
-    total = max(int(cumw[-1]), 1)
-    best = None
-    for g in candidates:
-        if g > k_dim:
-            break
-        equal = (np.arange(k_dim, dtype=np.int64) * g) // k_dim
-        # balanced: cut the cumulative (a+b) mass into g equal slices; the
-        # inclusive prefix keeps the map monotone and in [0, g)
-        balanced = np.minimum((cumw - weight) * g // total, g - 1)
-        for bin_of_k in (equal, balanced):
-            cost, ca, cb = score(bin_of_k, g)
-            if best is None or cost < best[0]:
-                best = (cost, g, ca, cb, bin_of_k.astype(np.int32))
-    cost, g, ca, cb, bin_of_k = best
-    return KBinPlan(
-        num_bins=g,
-        bin_cap_a=ca,
-        bin_cap_b=cb,
-        pairings=cost,
-        pairings_unbinned=cap_a * cap_b,
-        bin_of_k=bin_of_k,
-    )
 
 
 def rup8(x: int) -> int:

@@ -4,23 +4,24 @@ pure-jnp oracles (ref.py) and jit'd wrappers (ops.py).
   spmm.py          sparse × dense   (MoE dispatch; dense-accumulator SpGEMM)
   spgemm_acc.py    COO × COO → dense tile (sort-free paired SpGEMM, the
                    paper's hash-SpGEMM adapted to the MXU/VMEM)
-  spgemm_binned.py k-binned paired SpGEMM: counting-sort both operands by
-                   contraction range, pair only matching k-bins —
-                   O(Σ_g capA_g×capB_g) pairings instead of O(capA×capB)
-                   (Nagasaka-style binning, arXiv:1804.01698)
+  spgemm_hash.py   hash-accumulator insert rounds of the hash local
+                   multiply (plain XLA: a data-dependent table scatter)
+  col_prune.py     per-column top-k bisection of the dense MCL path
   sort_engine.py   in-VMEM bitonic sort of packed (row,col)-key/value pairs —
                    the on-chip sort primitive behind the packed-key
                    sort/compress engine in ``repro.core.sortkeys``
   densify.py       COO → dense tile scatter
 
-See DESIGN.md §3 for the CPU-hash → TPU-dense-accumulator adaptation story;
-``repro.core.sortkeys`` documents the packed-key encoding and engine policy.
+``backend.resolve_interpret`` makes the one call-time compiled-or-interpret
+decision; ``repro.core.sortkeys`` documents the packed-key encoding and
+engine policy.
+Only ``col_prune`` and ``spgemm_hash`` are on the SpGEMM main path; the
+``ops`` wrappers default to their pure-jnp oracles.
 """
 from . import ops, ref  # noqa: F401
 from .ops import (  # noqa: F401
     densify,
     sort_pairs,
     spgemm_paired,
-    spgemm_paired_binned,
     spmm,
 )

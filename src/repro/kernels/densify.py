@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .backend import resolve_interpret
+
 DEFAULT_BLOCKS = dict(m_blk=128, n_blk=128, nnz_blk=512)
 
 
@@ -45,7 +47,7 @@ def _densify_kernel(rows_ref, cols_ref, vals_ref, out_ref, *, m_blk, n_blk):
 
 def densify_pallas(
     rows, cols, vals, m: int, n: int,
-    *, m_blk=None, n_blk=None, nnz_blk=None, interpret: bool = True,
+    *, m_blk=None, n_blk=None, nnz_blk=None, interpret: bool = None,
 ) -> jnp.ndarray:
     cap = rows.shape[0]
     m_blk = min(m_blk or DEFAULT_BLOCKS["m_blk"], _rup(m, 8))
@@ -67,7 +69,7 @@ def densify_pallas(
         ],
         out_specs=pl.BlockSpec((m_blk, n_blk), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rows, cols, vals)
     return out[:m, :n]
 

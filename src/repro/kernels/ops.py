@@ -1,12 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 ``use_pallas`` selects the execution path:
-  * False (default on CPU): pure-jnp oracle path (``ref.py`` semantics) — this
-    is what the dry-run lowers, since Mosaic kernels don't lower to the CPU
-    backend.
-  * True: pl.pallas_call. On this container that means ``interpret=True``
-    (validation); on a real TPU pod the same call sites run compiled
-    (``interpret=False``).
+  * False (default): pure-jnp oracle path (``ref.py`` semantics).
+  * True: pl.pallas_call — compiled on a TPU, interpret mode elsewhere
+    (``interpret=None`` resolves through ``kernels.backend`` at call time).
+    These kernels are off the SpGEMM main path and have not been compiled
+    for the TPU.
 
 These wrappers accept the core ``SparseCOO`` type so the rest of the stack
 never touches raw entry lists.
@@ -23,15 +22,12 @@ from . import ref
 from .densify import densify_pallas
 from .sort_engine import sort_pairs as _sort_pairs
 from .spgemm_acc import spgemm_paired_pallas
-from .spgemm_binned import spgemm_binned_dense
 from .spmm import spmm_pallas
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def spmm(a: SparseCOO, b_dense: jnp.ndarray, use_pallas: bool = False,
-         interpret: bool = not _ON_TPU) -> jnp.ndarray:
+         interpret: bool = None) -> jnp.ndarray:
     """Sparse (m×k) × dense (k×n) → dense (m×n) f32."""
     m, _ = a.shape
     vals = jnp.where(a.valid_mask(), a.vals, 0)
@@ -42,7 +38,7 @@ def spmm(a: SparseCOO, b_dense: jnp.ndarray, use_pallas: bool = False,
 
 @partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def spgemm_paired(a: SparseCOO, b: SparseCOO, use_pallas: bool = False,
-                  interpret: bool = not _ON_TPU) -> jnp.ndarray:
+                  interpret: bool = None) -> jnp.ndarray:
     """Sparse (m×k) × sparse (k×n) → dense (m×n) f32 — sort-free paired kernel."""
     m, k = a.shape
     k2, n = b.shape
@@ -58,7 +54,7 @@ def spgemm_paired(a: SparseCOO, b: SparseCOO, use_pallas: bool = False,
 
 @partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def densify(a: SparseCOO, use_pallas: bool = False,
-            interpret: bool = not _ON_TPU) -> jnp.ndarray:
+            interpret: bool = None) -> jnp.ndarray:
     """Padded COO → dense (m×n) f32."""
     m, n = a.shape
     vals = jnp.where(a.valid_mask(), a.vals, 0)
@@ -67,43 +63,9 @@ def densify(a: SparseCOO, use_pallas: bool = False,
     return ref.densify_ref(a.rows, a.cols, vals, m, n)
 
 
-@partial(
-    jax.jit,
-    static_argnames=("num_bins", "bin_cap_a", "bin_cap_b", "use_pallas", "interpret"),
-)
-def spgemm_paired_binned(
-    a: SparseCOO,
-    b: SparseCOO,
-    num_bins: int,
-    bin_cap_a: int,
-    bin_cap_b: int,
-    bin_map: jnp.ndarray = None,
-    use_pallas: bool = False,
-    interpret: bool = not _ON_TPU,
-):
-    """k-binned paired SpGEMM: bucket both operands by contraction range, pair
-    only matching k-bins — O(Σ_g capA_g×capB_g) instead of O(capA×capB).
-
-    Static bin parameters (and the monotone ``bin_map`` absorbing skewed-k
-    distributions) come from ``repro.core.symbolic.plan_k_bins``. Returns
-    (C dense f32, overflow) — overflow > 0 means a bin capacity was exceeded
-    and entries were dropped (caller re-plans with bigger caps).
-    """
-    m, k = a.shape
-    k2, n = b.shape
-    assert k == k2
-    av = jnp.where(a.valid_mask(), a.vals, 0)
-    bv = jnp.where(b.valid_mask(), b.vals, 0)
-    return spgemm_binned_dense(
-        a.rows, a.cols, av, a.valid_mask(), b.rows, b.cols, bv, b.valid_mask(),
-        m, n, k, num_bins, bin_cap_a, bin_cap_b, bin_map=bin_map,
-        use_pallas=use_pallas, interpret=interpret,
-    )
-
-
 @partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def sort_pairs(keys: jnp.ndarray, vals: jnp.ndarray, use_pallas: bool = False,
-               interpret: bool = not _ON_TPU):
+               interpret: bool = None):
     """Single-key sort carrying one payload — the packed-key engine's sort
     primitive (bitonic VMEM network under Pallas, ``lax.sort`` otherwise)."""
     return _sort_pairs(keys, vals, use_pallas=use_pallas, interpret=interpret)

@@ -34,29 +34,6 @@ def densify_ref(rows: Array, cols: Array, vals: Array, m: int, n: int) -> Array:
     return out[:m, :n].astype(vals.dtype)
 
 
-def spgemm_paired_binned_ref(
-    a_rows: Array,
-    a_k: Array,
-    a_vals: Array,
-    b_k: Array,
-    b_cols: Array,
-    b_vals: Array,
-    m: int,
-    n: int,
-) -> Array:
-    """k-binned paired SpGEMM oracle: inputs are (num_bins, bin_cap*) arrays
-    from ``spgemm_binned.bin_entries_by_k``; only same-bin entries are paired,
-    so the work is Σ_g binA×binB — the same pairing set the binned Pallas
-    grid evaluates (cross-bin pairs are structurally impossible matches)."""
-    num_bins = a_rows.shape[0]
-    out = jnp.zeros((m, n), jnp.float32)
-    for g in range(num_bins):
-        out = out + spgemm_paired_ref(
-            a_rows[g], a_k[g], a_vals[g], b_k[g], b_cols[g], b_vals[g], m, n
-        ).astype(jnp.float32)
-    return out
-
-
 def spgemm_paired_ref(
     a_rows: Array,
     a_cols: Array,

@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .backend import resolve_interpret
+
 #: Largest pair count sorted on-chip: 2 i32/f32 arrays × a few network copies
 #: must fit in ~16 MB VMEM with headroom.
 MAX_BITONIC_ELEMS = 1 << 14
@@ -66,7 +68,7 @@ def _bitonic_kernel(k_ref, v_ref, ko_ref, vo_ref, *, length: int):
     vo_ref[...] = vals
 
 
-def bitonic_sort_pairs_pallas(keys, vals, *, interpret: bool = True):
+def bitonic_sort_pairs_pallas(keys, vals, *, interpret: bool = None):
     """Sort ``keys`` ascending carrying ``vals``; length must be a power of 2."""
     (length,) = keys.shape
     assert length & (length - 1) == 0, f"length {length} not a power of two"
@@ -79,7 +81,7 @@ def bitonic_sort_pairs_pallas(keys, vals, *, interpret: bool = True):
             jax.ShapeDtypeStruct((length,), keys.dtype),
             jax.ShapeDtypeStruct((length,), vals.dtype),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(keys, vals)
 
 
@@ -92,7 +94,7 @@ def sort_pairs(
     vals,
     *,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = None,
     max_bitonic: int = MAX_BITONIC_ELEMS,
 ):
     """Single-key sort of (keys, vals): bitonic Pallas network for VMEM-resident

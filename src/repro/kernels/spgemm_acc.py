@@ -18,12 +18,7 @@ Per (m-tile i, n-tile j) output block, reducing over A blocks s and B blocks t:
 
 Work is O(capA × capB) pairings per output tile — the narrow output blocks
 produced by batching (Alg. 4) keep capB small, which is what makes this
-profitable; the ESC path covers the wide/unbatched regime. When entries
-spread over the contraction index, ``spgemm_binned.py`` cuts the pairing
-work to O(Σ_k capA_k × capB_k) by bucketing both operands by k-range first
-and pairing only matching bins — use ``repro.core.symbolic.plan_k_bins`` to
-size the bins and prefer the binned kernel whenever its planned pairing
-count is lower.
+profitable; the ESC path covers the wide/unbatched regime.
 """
 from __future__ import annotations
 
@@ -32,6 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .backend import resolve_interpret
 
 DEFAULT_BLOCKS = dict(m_blk=128, n_blk=128, a_blk=256, b_blk=256)
 
@@ -66,7 +63,7 @@ def _paired_kernel(
 
 def spgemm_paired_pallas(
     a_rows, a_cols, a_vals, b_rows, b_cols, b_vals, m: int, n: int,
-    *, m_blk=None, n_blk=None, a_blk=None, b_blk=None, interpret: bool = True,
+    *, m_blk=None, n_blk=None, a_blk=None, b_blk=None, interpret: bool = None,
 ) -> jnp.ndarray:
     """Dense C (m×n, f32) from two padded COO entry lists (zero-valued padding)."""
     capA, capB = a_rows.shape[0], b_rows.shape[0]
@@ -102,7 +99,7 @@ def spgemm_paired_pallas(
         ],
         out_specs=pl.BlockSpec((m_blk, n_blk), lambda i, j, s, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_rows, a_cols, a_vals, b_rows, b_cols, b_vals)
     return out[:m, :n]
 

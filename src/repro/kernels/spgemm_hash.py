@@ -1,16 +1,16 @@
-"""Pallas TPU kernel: hash-accumulator insert for SpGEMM — O(output) scratch.
+"""Hash-accumulator insert for SpGEMM — O(output) scratch.
 
 The ESC local multiply materializes the *whole* expansion (O(flops) entries)
 before sorting and compressing it.  Following Nagasaka et al.'s hash SpGEMM
-(arXiv:1804.01698), this kernel instead consumes partial products on the fly:
-each (packed row-major key, value) pair is inserted into a VMEM-resident
-open-addressing table and semiring-accumulated in place on a probe hit, so
-the resident structure is O(nnz(C) · load_factor) — the table — plus one
-bounded, reused chunk buffer.  High compression-factor batches (flops ≫
-nnz(C)) are exactly where this wins the memory budget.
+(arXiv:1804.01698), this path instead consumes partial products on the fly:
+each (packed row-major key, value) pair is inserted into an open-addressing
+table and semiring-accumulated in place on a probe hit, so the resident
+structure is O(nnz(C) · load_factor) — the table — plus one bounded, reused
+chunk buffer.  High compression-factor batches (flops ≫ nnz(C)) are exactly
+where this wins the memory budget.
 
-Insertion is formulated as vectorized probe *rounds* so it maps onto the VPU
-(no per-entry serial loop):
+Insertion is formulated as vectorized probe *rounds* (no per-entry serial
+loop):
 
   round p: every still-unplaced entry probes slot (h0 + p) & (T - 1)
            — a hit on its own key accumulates next reduction;
@@ -29,17 +29,18 @@ Keys are ``sortkeys.pack_rowmajor`` i32 keys; ``EMPTY`` is INT32_MAX, which
 also sorts after every real key *and* every sentinel, so the final table →
 sorted-COO compaction is one ``lax.sort`` + ``compress_sorted_keys``.
 
-The scatter-claim (`.at[dest].min`) lowers in interpret mode and on the CPU
-oracle path; on Mosaic the same rounds run with the table in VMEM scratch.
+The insert is plain XLA on every backend. The claim is a data-dependent
+gather/scatter into an HBM-sized table, which Mosaic does not lower (it
+supports only 2-D gathers), so there is no Pallas variant. Losing claims
+and unplaced entries scatter to index ``T`` with ``mode="drop"``: the table
+is updated in place, never padded and re-sliced per round.
 """
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from ..core.sortkeys import INT32_MAX
 
@@ -48,8 +49,7 @@ SLOT_BYTES = 8
 
 # Fibonacci multiplicative hashing: the golden-ratio constant scrambles the
 # packed keys' low-entropy structure (row*(n+1)+col clusters by row) before
-# the top-bits cut selects a slot. Python ints (not jnp constants) so the
-# Pallas kernel doesn't close over traced arrays.
+# the top-bits cut selects a slot.
 _FIB = 2654435769
 
 EMPTY = INT32_MAX
@@ -65,52 +65,55 @@ def fib_hash(keys: jnp.ndarray, lg_table: int) -> jnp.ndarray:
 
 
 def _insert_rounds(table_key, keys, valid, max_probes: int):
-    """Run the vectorized probe rounds; returns (table_key, placed, slot_of)."""
+    """Run the vectorized probe rounds; returns (table_key, placed, slot_of).
+
+    Rounds stop once every valid entry is placed (at the planned load
+    factor most entries place in the first few probes) or after
+    ``max_probes`` rounds, whichever comes first.
+    """
     table_cap = table_key.shape[0]
     assert table_cap & (table_cap - 1) == 0, table_cap
     lg = table_cap.bit_length() - 1
     h0 = fib_hash(keys, lg)
 
-    def body(p, carry):
-        tk, placed, slot_of = carry
+    def unplaced(carry):
+        p, _, placed, _ = carry
+        return (p < max_probes) & jnp.any(valid & ~placed)
+
+    def body(carry):
+        p, tk, placed, slot_of = carry
         slot = (h0 + p) & (table_cap - 1)
         cur = tk[slot]
         live = valid & ~placed
         match = live & (cur == keys)
         empty = live & (cur == EMPTY)
-        # claim EMPTY slots by scatter-min of the key; index table_cap is the
-        # discard slot of the padded table, so occupied slots are untouched
+        # claim EMPTY slots by scatter-min of the key; index table_cap is out
+        # of bounds and dropped, so occupied slots are untouched
         dest = jnp.where(empty, slot, table_cap)
-        tk = jnp.concatenate([tk, jnp.full((1,), EMPTY, jnp.int32)])
-        tk = tk.at[dest].min(jnp.where(empty, keys, EMPTY))[:table_cap]
+        tk = tk.at[dest].min(jnp.where(empty, keys, EMPTY), mode="drop")
         won = empty & (tk[slot] == keys)
         placed_now = match | won
         slot_of = jnp.where(placed_now, slot, slot_of)
-        return tk, placed | placed_now, slot_of
+        return p + 1, tk, placed | placed_now, slot_of
 
     placed0 = jnp.zeros(keys.shape, bool)
     slot0 = jnp.zeros(keys.shape, jnp.int32)
-    return jax.lax.fori_loop(
-        0, max_probes, body, (table_key, placed0, slot0)
+    _, table_key, placed, slot_of = jax.lax.while_loop(
+        unplaced, body, (jnp.int32(0), table_key, placed0, slot0)
     )
+    return table_key, placed, slot_of
 
 
 def _accumulate(table_val, vals, placed, slot_of, add_kind: str):
     """Semiring-reduce placed values into their slots (one scatter)."""
     table_cap = table_val.shape[0]
-    seg = jnp.where(placed, slot_of, table_cap)  # discard slot for unplaced
+    seg = jnp.where(placed, slot_of, table_cap)  # out of bounds: dropped
     if add_kind == "sum":
-        pad = jnp.zeros((1,), table_val.dtype)
-        contrib = jnp.where(placed, vals, 0).astype(table_val.dtype)
-        return jnp.concatenate([table_val, pad]).at[seg].add(contrib)[:table_cap]
+        return table_val.at[seg].add(vals.astype(table_val.dtype), mode="drop")
     if add_kind == "min":
-        pad = jnp.full((1,), jnp.inf, table_val.dtype)
-        contrib = jnp.where(placed, vals, jnp.inf).astype(table_val.dtype)
-        return jnp.concatenate([table_val, pad]).at[seg].min(contrib)[:table_cap]
+        return table_val.at[seg].min(vals.astype(table_val.dtype), mode="drop")
     assert add_kind == "max", add_kind
-    pad = jnp.full((1,), -jnp.inf, table_val.dtype)
-    contrib = jnp.where(placed, vals, -jnp.inf).astype(table_val.dtype)
-    return jnp.concatenate([table_val, pad]).at[seg].max(contrib)[:table_cap]
+    return table_val.at[seg].max(vals.astype(table_val.dtype), mode="drop")
 
 
 def table_init_val(add_kind: str) -> float:
@@ -119,10 +122,10 @@ def table_init_val(add_kind: str) -> float:
     return {"sum": 0.0, "min": float("inf"), "max": float("-inf")}[add_kind]
 
 
-def hash_insert_ref(
+def hash_insert(
     table_key, table_val, keys, vals, valid, *, add_kind: str, max_probes: int
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Pure-jnp oracle: insert one chunk of (key, val) partial products.
+    """Insert one chunk of (key, val) partial products into the table.
 
     Returns (table_key, table_val, dropped): the updated table and the count
     of valid entries that found neither their key nor an EMPTY slot within
@@ -135,79 +138,3 @@ def hash_insert_ref(
     table_val = _accumulate(table_val, vals, placed, slot_of, add_kind)
     dropped = jnp.sum((valid & ~placed).astype(jnp.int32))
     return table_key, table_val, dropped
-
-
-# ---------------------------------------------------------------------------
-# Pallas kernel: same rounds, table resident in VMEM
-# ---------------------------------------------------------------------------
-def _hash_insert_kernel(
-    keys_ref, vals_ref, valid_ref, tk_in_ref, tv_in_ref,
-    tk_ref, tv_ref, drop_ref, *, add_kind: str, max_probes: int,
-):
-    keys = keys_ref[0, :]
-    vals = vals_ref[0, :]
-    valid = valid_ref[0, :] != 0
-    tk, placed, slot_of = _insert_rounds(
-        tk_in_ref[0, :], keys, valid, max_probes
-    )
-    tv = _accumulate(tv_in_ref[0, :], vals, placed, slot_of, add_kind)
-    tk_ref[0, :] = tk
-    tv_ref[0, :] = tv
-    drop_ref[0, 0] = jnp.sum((valid & ~placed).astype(jnp.int32))
-
-
-def hash_insert_pallas(
-    table_key, table_val, keys, vals, valid,
-    *, add_kind: str, max_probes: int, interpret: bool = True,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One pallas_call per chunk: whole table + chunk as single VMEM blocks
-    (table_cap and chunk_cap are planner-bounded VMEM-resident sizes)."""
-    table_cap = table_key.shape[0]
-    chunk_cap = keys.shape[0]
-    tk, tv, drop = pl.pallas_call(
-        functools.partial(
-            _hash_insert_kernel, add_kind=add_kind, max_probes=max_probes
-        ),
-        in_specs=[
-            pl.BlockSpec((1, chunk_cap), lambda: (0, 0)),
-            pl.BlockSpec((1, chunk_cap), lambda: (0, 0)),
-            pl.BlockSpec((1, chunk_cap), lambda: (0, 0)),
-            pl.BlockSpec((1, table_cap), lambda: (0, 0)),
-            pl.BlockSpec((1, table_cap), lambda: (0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, table_cap), lambda: (0, 0)),
-            pl.BlockSpec((1, table_cap), lambda: (0, 0)),
-            pl.BlockSpec((1, 1), lambda: (0, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((1, table_cap), jnp.int32),
-            jax.ShapeDtypeStruct((1, table_cap), table_val.dtype),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        ),
-        interpret=interpret,
-    )(
-        keys.reshape(1, -1),
-        vals.reshape(1, -1),
-        valid.astype(jnp.int32).reshape(1, -1),
-        table_key.reshape(1, -1),
-        table_val.reshape(1, -1),
-    )
-    return tk[0], tv[0], drop[0, 0]
-
-
-def hash_insert(
-    table_key, table_val, keys, vals, valid,
-    *, add_kind: str, max_probes: int,
-    use_pallas: bool = False, interpret: bool = True,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Dispatch one chunk insert to the Pallas kernel or the jnp oracle."""
-    if use_pallas:
-        return hash_insert_pallas(
-            table_key, table_val, keys, vals, valid,
-            add_kind=add_kind, max_probes=max_probes, interpret=interpret,
-        )
-    return hash_insert_ref(
-        table_key, table_val, keys, vals, valid,
-        add_kind=add_kind, max_probes=max_probes,
-    )

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .backend import resolve_interpret
+
 DEFAULT_BLOCKS = dict(m_blk=128, n_blk=128, k_blk=512, nnz_blk=512)
 
 
@@ -72,13 +74,12 @@ def spmm_pallas(
     n_blk: int = None,
     k_blk: int = None,
     nnz_blk: int = None,
-    interpret: bool = True,
+    interpret: bool = None,
 ) -> jnp.ndarray:
     """C (m×n, f32) = scatter-accumulate of A's padded COO against dense B.
 
     Dimensions are padded up to block multiples here; callers pass natural
-    shapes. ``interpret=True`` executes on CPU for validation; on TPU pass
-    ``interpret=False``.
+    shapes. ``interpret=None`` compiles on a TPU and interprets elsewhere.
     """
     cap = rows.shape[0]
     k, n = b.shape
@@ -110,7 +111,7 @@ def spmm_pallas(
         ],
         out_specs=pl.BlockSpec((m_blk, n_blk), lambda i, j, kk, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rows, cols, vals, b)
     return out[:m, :n]
 

@@ -5,9 +5,8 @@ never touch jax device state (the dry-run sets XLA_FLAGS before first init).
 """
 from __future__ import annotations
 
-import jax  # noqa: F401 — kept for device queries by callers
-
-from ..compat import AxisType, make_mesh
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,16 +18,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, pod: int = 0):
     """Small host-device mesh for tests/examples (needs XLA host-device flag)."""
     if pod:
-        return make_mesh(
+        return jax.make_mesh(
             (pod, data, model), ("pod", "data", "model"),
             axis_types=(AxisType.Auto,) * 3,
         )
-    return make_mesh(
+    return jax.make_mesh(
         (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
     )

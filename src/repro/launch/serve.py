@@ -30,6 +30,7 @@ def main() -> int:
 
     import numpy as np
 
+    from ..compile_cache import enable_compile_cache
     from ..core.gen import erdos_renyi
     from ..core.grid import make_grid
     from ..runtime.resilient import (
@@ -38,6 +39,7 @@ def main() -> int:
     )
     from ..serve import MultiplyRequest, ServeConfig, SpgemmEngine
 
+    enable_compile_cache()
     install_preemption_handler()
     grid = make_grid(args.pr, args.pr, args.layers)
     eng = SpgemmEngine(grid, ServeConfig(per_process_memory=args.memory))

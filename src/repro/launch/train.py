@@ -39,7 +39,7 @@ def main() -> int:
 
     import jax
     import numpy as np
-    from ..compat import AxisType, make_mesh, set_mesh
+    from jax.sharding import AxisType
 
     from ..configs import get_config
     from ..data import DataConfig, synthetic_batch
@@ -57,11 +57,11 @@ def main() -> int:
         mesh = make_production_mesh(multi_pod=True)
     elif args.mesh == "auto":
         model = 2 if ndev >= 4 else 1
-        mesh = make_mesh((ndev // model, model), ("data", "model"),
+        mesh = jax.make_mesh((ndev // model, model), ("data", "model"),
                          axis_types=(AxisType.Auto,) * 2)
     else:
         d, m = (int(v) for v in args.mesh.split("x"))
-        mesh = make_mesh((d, m), ("data", "model"),
+        mesh = jax.make_mesh((d, m), ("data", "model"),
                          axis_types=(AxisType.Auto,) * 2)
     print(f"arch={cfg.arch_id} mesh={dict(zip(mesh.axis_names, mesh.devices.shape))}")
 
@@ -80,7 +80,7 @@ def main() -> int:
         return {"params": params, "opt": adamw.init_opt_state(params)}
 
     def wrapped_step(state, batch_):
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             p, o, m = step_fn(state["params"], state["opt"], batch_)
         return {"params": p, "opt": o}, m
 

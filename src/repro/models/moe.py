@@ -26,8 +26,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map as _shard_map
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.local_spgemm import spmm

@@ -458,7 +458,7 @@ def _sharded_xent(cfg: ModelConfig, mesh, logits: Array, targets: Array) -> Arra
     def local(lg, tg):
         return _vp_xent_local(lg.astype(jnp.float32), tg)
 
-    from ..compat import shard_map as _shard_map
+    from jax import shard_map as _shard_map
 
     nll = _shard_map(
         local, mesh=mesh, in_specs=(lspec, tspec), out_specs=tspec,

@@ -16,7 +16,7 @@ routine. `run_iterated` is the generic loop:
     degrades to a cold start;
   * **plan-signature meta** rides in the checkpoint manifest (`store.save
     (meta=...)`): the workload's encode/decode callbacks snapshot the pow2/
-    floor caps, pinned k-bin signature, hash caps, local path and
+    floor caps, hash caps, local path and
     batch-count floor next to the iterate, so the restored loop rebuilds the
     IDENTICAL fused-step static signature — zero extra retraces after a
     resume (asserted via ``summa3d.TRACE_COUNTS`` in the tests);

@@ -10,9 +10,9 @@ in-flight work is DEFERRED (FIFO, no overtaking); one that cannot fit the
 when no split fits.
 
 The plan cache is keyed by the matrix signature — global shape, pow2 nnz
-profile, pow2 scatter capacities, pow2 k-bin profile (max per-column
-counts), semiring, local-path policy, mask id — and stores the pow2/floor
-capacities of the last plan with that signature as one ``PlanFloors``.
+profile, pow2 scatter capacities, pow2 max per-column count, semiring,
+local-path policy, mask id — and stores the pow2/floor capacities of the
+last plan with that signature as one ``PlanFloors``.
 Repeat traffic re-plans through ``plan_batches(spec=..., floors=...)`` with
 the cached floors, landing on the IDENTICAL fused-step static signature: the
 dispatch
@@ -49,7 +49,7 @@ from ..core.distsparse import DistSparse, scatter_to_grid, tile_nnz_counts
 from ..core.grid import Grid
 from ..core.sparse import SparseCOO, from_numpy_coo
 from ..core.specs import PlanFloors, PlanSpec
-from ..core.summa3d import BatchCaps, BinnedCaps, HashCaps
+from ..core.summa3d import BatchCaps, HashCaps
 from ..core.symbolic import rup8 as _rup8, rup_pow2 as _rup_pow2
 from ..runtime.driver import LookaheadWindow
 
@@ -79,7 +79,7 @@ class ServeConfig:
     lookahead: int = 2  # in-flight window depth (shared across requests)
     max_retries: int = 4  # per-batch overflow retry bound
     max_splits: int = 3  # admission force_num_batches doublings before refusal
-    local_path: str = "auto"  # 3-way local-multiply policy (part of the key)
+    local_path: str = "auto"  # local-multiply policy (part of the key)
     # base capacity floors applied to every FIRST plan of a signature (an
     # autotuner warm-start: repeat traffic still folds its own floors on top)
     seed_floors: Optional[PlanFloors] = None
@@ -146,8 +146,6 @@ class _Active:
     nb: int
     caps: BatchCaps
     sel_cap: int
-    kb: Optional[BinnedCaps]
-    bin_of_k: Optional[jnp.ndarray]
     hc: Optional[HashCaps]
     mask_cap: int
     price: int
@@ -279,23 +277,6 @@ class SpgemmEngine:
                 f"after {splits} splits"
             )
         use_hash = plan.local_path == "hash"
-        use_binned = (
-            not use_hash and plan.local_path == "binned"
-            and req.semiring.name == "plus_times"
-        )
-        kb = None
-        if use_binned:
-            kb = BinnedCaps(
-                plan.kbin.num_bins, _rup_pow2(plan.kbin.bin_cap_a),
-                _rup_pow2(plan.kbin.bin_cap_b),
-            )
-            prior_kb = entry.floors.kbin_caps if entry is not None else None
-            if prior_kb is not None:
-                kb = BinnedCaps(
-                    kb.num_bins,
-                    max(kb.bin_cap_a, prior_kb.bin_cap_a),
-                    max(kb.bin_cap_b, prior_kb.bin_cap_b),
-                )
         # the cache entry is written at PLAN time (not completion) so repeat
         # traffic hits even while the first request with this signature is
         # still in flight; completion folds any retry growth back in.
@@ -308,7 +289,6 @@ class SpgemmEngine:
                 floors=PlanFloors(
                     caps=plan.caps, sel_cap=plan.sel_cap,
                     num_batches=plan.num_batches,
-                    kbin_caps=kb,
                     hash_caps=(plan.hash_caps if use_hash else None),
                     caps_pow2=True,
                 ),
@@ -318,8 +298,6 @@ class SpgemmEngine:
         return _Active(
             req=req, key=key, plan=plan, A=A, B=B, M=M,
             nb=plan.num_batches, caps=plan.caps, sel_cap=plan.sel_cap,
-            kb=kb, bin_of_k=(jnp.asarray(plan.kbin.bin_of_k) if use_binned
-                             else None),
             hc=(plan.hash_caps if use_hash else None),
             mask_cap=plan.mask_sel_cap, price=price, splits=splits,
             plan_cached=entry is not None,
@@ -363,10 +341,10 @@ class SpgemmEngine:
     # -- dispatch / finish -------------------------------------------------
     def _dispatch(self, act: _Active, bi: int):
         return _fused_jit(
-            act.A, act.B, jnp.int32(bi), act.bin_of_k, act.M,
+            act.A, act.B, jnp.int32(bi), act.M,
             grid=self.grid, num_batches=act.nb, sel_cap=act.sel_cap,
             caps=act.caps, semiring=act.req.semiring, sorted_merge=True,
-            path="sparse", kbin=act.kb, hashc=act.hc, mask_cap=act.mask_cap,
+            path="sparse", hashc=act.hc, mask_cap=act.mask_cap,
             mask_complement=False,
         )
 
@@ -386,7 +364,6 @@ class SpgemmEngine:
             elif o[1] > 0:
                 act.caps = act.caps.doubled()
                 act.hc = act.hc.doubled() if act.hc is not None else None
-                act.kb = act.kb.doubled() if act.kb is not None else None
                 if act.M is not None:
                     act.mask_cap = min(act.mask_cap * 2, act.M.cap)
             c_batch, ovf = self._dispatch(act, bi)
@@ -414,7 +391,7 @@ class SpgemmEngine:
             entry = self.plan_cache[act.key]
             entry.floors = entry.floors.merged(PlanFloors(
                 caps=act.caps, sel_cap=act.sel_cap, num_batches=act.nb,
-                kbin_caps=act.kb, hash_caps=act.hc, caps_pow2=True,
+                hash_caps=act.hc, caps_pow2=True,
             ))
             entry.price_bytes = max(entry.price_bytes, act.price)
             self.stats["served"] += 1

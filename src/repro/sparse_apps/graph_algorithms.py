@@ -34,9 +34,8 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 
-from ..compat import shard_map
 from ..core import distsparse
 from ..core import semiring as sr
 from ..core.batched import RunReport, batched_summa3d
@@ -376,16 +375,15 @@ class APSPConfig:
     force_num_batches: Optional[int] = None
     lookahead: int = 2
     r_bytes: int = 12
-    # 3-way local dispatch; k-binned is plus_times-only and auto-disabled,
-    # ESC and the hash accumulator are semiring-generic
+    # local dispatch; ESC and the hash accumulator are semiring-generic
     local_path: str = "auto"
 
 
 @dataclasses.dataclass
 class APSPLoopState:
     """Device-resident iterate (A/B operands of the next squaring) +
-    plan-signature floors (the checkpointed unit; mirrors `mcl.MCLLoopState`
-    minus the k-binned signature, which min_plus never uses)."""
+    plan-signature floors (the checkpointed unit; mirrors
+    `mcl.MCLLoopState`)."""
 
     A: DistSparse
     B: DistSparse
@@ -512,7 +510,7 @@ def _apsp_step(
             **({"slack": slack} if slack is not None else {}),
         ),
         floors=state.floors.replace(caps_pow2=True),
-        exec_spec=ExecSpec(lookahead=cfg.lookahead, binned=False),
+        exec_spec=ExecSpec(lookahead=cfg.lookahead),
     )
     state.floors = state.floors.merged(res.floors())
     state.lp_arg = res.local_path

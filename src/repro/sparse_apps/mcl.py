@@ -49,9 +49,8 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 
-from ..compat import shard_map
 from ..core import distsparse
 from ..core.batched import RunReport, batched_summa3d
 from ..core.distsparse import DistSparse, dist_spec, local_col_reduce
@@ -80,8 +79,7 @@ class MCLConfig:
     force_num_batches: Optional[int] = None  # None: symbolic-step planning
     lookahead: int = 2  # pipelined driver window
     r_bytes: int = 12  # bytes per stored nonzero (COO: i32+i32+f32)
-    binned: object = "auto"  # sparse local multiply: "auto" | True | False
-    # 3-way local-multiply dispatch: "auto" | "esc" | "binned" | "hash"
+    # local-multiply dispatch: "auto" | "esc" | "hash"
     local_path: str = "auto"
 
 
@@ -316,8 +314,6 @@ def _mcl_prune_dense(c_tiles, grid: Grid, inflation: float, thresh: float, k: in
     row-gathered column block (the batch column is split across the pr row
     tiles, so the kernel sees the full column). Returns (pruned tiles, stats).
     """
-    interpret = jax.default_backend() != "tpu"
-
     def step(x):
         t = x.reshape(x.shape[-2:]).astype(jnp.float32)  # (tm, wbl)
         tm = t.shape[0]
@@ -325,7 +321,7 @@ def _mcl_prune_dense(c_tiles, grid: Grid, inflation: float, thresh: float, k: in
         colsum = lax.psum(jnp.sum(t, axis=0), ROW_AX)
         t = t / jnp.where(colsum > 0, colsum, 1.0)[None, :]
         full = lax.all_gather(t, ROW_AX).reshape(-1, t.shape[1])
-        lo, thr = col_topk_bounds_pallas(full, k, interpret=interpret)
+        lo, thr = col_topk_bounds_pallas(full, k)
         # keep all strictly-greater entries, then fill the remaining top-k
         # slots from the [lo, thr) tie band by rank (a value repeated across
         # the k boundary would otherwise be pruned entirely); the full
@@ -398,9 +394,9 @@ def _extract_dense_batch(tiles: np.ndarray, col_map: np.ndarray):
 class MCLLoopState:
     """Everything one sparse MCL iteration carries to the next — the
     device-resident iterate (A/B operands) PLUS the plan signature: ONE
-    ``PlanFloors`` (pow2/floor caps, pinned k-bin caps, hash caps,
-    batch-count floor — it replaced four parallel floor attributes) and the
-    pinned binned/local-path decisions. The resilient loop checkpoints
+    ``PlanFloors`` (pow2/floor caps, hash caps, batch-count floor — it
+    replaced parallel floor attributes) and the pinned local-path decision.
+    The resilient loop checkpoints
     exactly this: the arrays via the content-hashed store, the signature as
     manifest meta — so a restored run replans to the IDENTICAL fused-step
     static signature and hits the jit cache (zero extra retraces after a
@@ -413,7 +409,6 @@ class MCLLoopState:
     history: List[dict]
     report: RunReport
     floors: PlanFloors = dataclasses.field(default_factory=PlanFloors)
-    binned_arg: object = "auto"
     lp_arg: object = "auto"
 
 
@@ -434,7 +429,7 @@ def _mcl_cold_state(a: SparseCOO, grid: Grid, cfg: MCLConfig) -> MCLLoopState:
     return MCLLoopState(
         A=_scatter(a, grid, "A"), B=_scatter(a, grid, "B"),
         it=0, chaos=float("inf"), history=[], report=RunReport(),
-        binned_arg=cfg.binned, lp_arg=cfg.local_path,
+        lp_arg=cfg.local_path,
     )
 
 
@@ -491,12 +486,11 @@ def _mcl_sparse_step(
             **({"slack": slack} if slack is not None else {}),
         ),
         floors=state.floors.replace(caps_pow2=True),
-        exec_spec=ExecSpec(lookahead=cfg.lookahead, binned=state.binned_arg),
+        exec_spec=ExecSpec(lookahead=cfg.lookahead),
     )
     # pin iteration 1's decisions + used capacities (monotone fold) so every
     # later iteration replans onto the same fused-step static signature
     state.floors = state.floors.merged(res.floors())
-    state.binned_arg = res.binned
     state.lp_arg = res.local_path
     state.A, state.B, ovf = reassemble_operands(
         tuple(batches), grid, cap_a, cap_b
@@ -576,18 +570,16 @@ def _dist_from_arrays(
 def _plan_sig_encode(state: MCLLoopState) -> dict:
     """JSON-safe plan signature: everything `plan_batches` needs to rebuild
     the identical fused-step static signature after a restore — the floors
-    round-trip through ``PlanFloors.to_meta`` plus the two pinned driver
-    decisions."""
+    round-trip through ``PlanFloors.to_meta`` plus the pinned local-path
+    decision."""
     return {
         "floors": state.floors.to_meta(),
-        "binned": state.binned_arg,
         "local_path": state.lp_arg,
     }
 
 
 def _plan_sig_decode(state: MCLLoopState, sig: dict) -> None:
     state.floors = PlanFloors.from_meta(sig["floors"])
-    state.binned_arg = sig["binned"]
     state.lp_arg = sig["local_path"]
 
 

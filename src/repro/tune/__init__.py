@@ -3,7 +3,7 @@
 ``cost_model`` prices a batch plan — per-batch and end-to-end — from the
 Table II α–β communication terms and per-path γ compute terms over the
 symbolic counts; ``autotune`` enumerates candidate (grid, local path, batch
-count, k-bin pinning, lookahead) configurations from ONE symbolic pass per
+count, lookahead) configurations from ONE symbolic pass per
 candidate grid (host math, no devices, no trial multiplies) and returns a
 ``TunedConfig`` — exactly a ``PlanSpec`` + ``PlanFloors`` + ``ExecSpec`` +
 grid shape, which ``batched_summa3d`` and the serving engine's admission

@@ -1,11 +1,10 @@
-"""Autotuner: enumerate (grid, path, batches, bins, lookahead) candidates
+"""Autotuner: enumerate (grid, path, batches, lookahead) candidates
 from symbolic counts alone and price them with the cost model.
 
 One ``host_symbolic_counts`` pass per candidate grid (host math over the
 COO — no scatter, no devices, no trial multiplies), then
-``plan_from_symbolic`` turns each (local path, forced batch count, k-bin
-pin) combination into a concrete ``BatchPlan`` that ``predict_cost``
-prices. The default configuration — the grid ``square_grid_for`` would
+``plan_from_symbolic`` turns each (local path, forced batch count)
+combination into a concrete ``BatchPlan`` that ``predict_cost`` prices. The default configuration — the grid ``square_grid_for`` would
 pick with ``PlanSpec()``/``ExecSpec()`` defaults — is ALWAYS in the
 candidate set, so the argmin is never priced worse than the defaults by
 construction (an acceptance criterion, asserted in tests).
@@ -33,7 +32,7 @@ from .cost_model import (
 
 #: local-multiply paths the tuner prices explicitly ("auto" lets the plan
 #: decide — the fixed-heuristic default the tuned pick must not lose to)
-PATHS = ("auto", "esc", "binned", "hash")
+PATHS = ("auto", "esc", "hash")
 
 #: placement strategies the tuner prices. ``None`` (no permutation) comes
 #: first and wins ties: a placement is only picked on a STRICT improvement
@@ -152,7 +151,7 @@ def autotune(
     r_bytes: int = 12,
     max_retries: int = 4,
 ) -> TunedConfig:
-    """Pick the cheapest (grid, path, batches, bins, lookahead) for
+    """Pick the cheapest (grid, path, batches, lookahead) for
     ``a @ b`` under ``per_process_memory`` — by symbolic pricing only.
 
     ``a``/``b`` (and the optional ``mask``) are HOST matrices (anything
@@ -190,58 +189,56 @@ def autotune(
             counts = host_symbolic_counts(pa, pb, grid, mask=pmask)
             inputs = PlanInputs.from_host(pa, pb, grid, mask=pmask)
             for path in PATHS:
-                for kbin_pin in (None, (1,)):
-                    spec = PlanSpec(local_path=path, r_bytes=r_bytes,
-                                    kbin_candidates=kbin_pin)
-                    try:
-                        plan = plan_from_symbolic(
-                            counts, inputs, per_process_memory, spec,
-                            PlanFloors(),
+                spec = PlanSpec(local_path=path, r_bytes=r_bytes)
+                try:
+                    plan = plan_from_symbolic(
+                        counts, inputs, per_process_memory, spec,
+                        PlanFloors(),
+                    )
+                except MemoryError:
+                    if strategy is None and grid == base_grid \
+                            and path == "auto":
+                        raise  # the default config itself is infeasible
+                    continue
+                nb_forced = (None, plan.num_batches * 2)
+                for force in nb_forced:
+                    if force is not None:
+                        try:
+                            plan_f = plan_from_symbolic(
+                                counts, inputs, per_process_memory,
+                                dataclasses.replace(
+                                    spec, force_num_batches=force),
+                                PlanFloors(),
+                            )
+                        except MemoryError:
+                            continue
+                    else:
+                        plan_f = plan
+                    for la in lookaheads:
+                        cost = predict_cost(
+                            plan_f, grid, inputs.nnz_a, inputs.nnz_b,
+                            coeffs=coeffs, r_bytes=r_bytes,
+                            pipelined=True, lookahead=la,
                         )
-                    except MemoryError:
-                        if strategy is None and grid == base_grid \
-                                and path == "auto" and kbin_pin is None:
-                            raise  # the default config itself is infeasible
-                        continue
-                    nb_forced = (None, plan.num_batches * 2)
-                    for force in nb_forced:
-                        if force is not None:
-                            try:
-                                plan_f = plan_from_symbolic(
-                                    counts, inputs, per_process_memory,
-                                    dataclasses.replace(
-                                        spec, force_num_batches=force),
-                                    PlanFloors(),
-                                )
-                            except MemoryError:
-                                continue
-                        else:
-                            plan_f = plan
-                        for la in lookaheads:
-                            cost = predict_cost(
-                                plan_f, grid, inputs.nnz_a, inputs.nnz_b,
-                                coeffs=coeffs, r_bytes=r_bytes,
-                                pipelined=True, lookahead=la,
-                            )
-                            padded = padded_comm_volume(
-                                plan_f, grid, r_bytes=r_bytes
-                            )
-                            is_default = (
-                                strategy is None and grid == base_grid
-                                and path == "auto" and kbin_pin is None
-                                and force is None
-                                and la == ExecSpec().lookahead
-                            )
-                            if is_default:
-                                baseline = (grid, plan_f, cost)
-                            cand = (grid, plan_f, cost, path, kbin_pin,
-                                    force, la, strategy)
-                            # lexicographic, strict: placements iterate
-                            # after None, so a permutation only wins when
-                            # it strictly lowers the (ms, padded-bytes) key
-                            key = (cost.total_ms, padded.total_bytes)
-                            if best is None or key < best_key:
-                                best, best_key = cand, key
+                        padded = padded_comm_volume(
+                            plan_f, grid, r_bytes=r_bytes
+                        )
+                        is_default = (
+                            strategy is None and grid == base_grid
+                            and path == "auto"
+                            and force is None
+                            and la == ExecSpec().lookahead
+                        )
+                        if is_default:
+                            baseline = (grid, plan_f, cost)
+                        cand = (grid, plan_f, cost, path, force, la,
+                                strategy)
+                        # lexicographic, strict: placements iterate
+                        # after None, so a permutation only wins when
+                        # it strictly lowers the (ms, padded-bytes) key
+                        key = (cost.total_ms, padded.total_bytes)
+                        if best is None or key < best_key:
+                            best, best_key = cand, key
 
     assert best is not None  # default grid either planned or raised
     if baseline is None:
@@ -261,16 +258,11 @@ def autotune(
                          lookahead=ExecSpec().lookahead),
         )
 
-    grid, plan, cost, path, kbin_pin, force, la, strategy = best
-    decided = plan.local_path
-    pin = kbin_pin
-    if pin is None and decided == "binned" and plan.kbin is not None:
-        pin = (plan.kbin.num_bins,)  # reproduce the priced bin structure
+    grid, plan, cost, path, force, la, strategy = best
     tuned_spec = PlanSpec(
-        local_path=decided,
+        local_path=plan.local_path,
         r_bytes=r_bytes,
         force_num_batches=force,
-        kbin_candidates=pin,
     )
     tuned_floors = PlanFloors(
         caps=plan.caps,

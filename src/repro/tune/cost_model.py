@@ -22,10 +22,7 @@ HLO collectives):
 
 Compute units per local-multiply path: ESC and hash pay γ per flop (the hash
 γ also covers its serialized per-chunk insert passes, which is why it is
-~100× the ESC γ per flop on this backend); the k-binned path pays the ESC
-merge cost plus γ_binned per PAIRING — ``b · KBinPlan.pairings``, the exact
-quantity the symbolic k-bin plan minimizes, so a pinned bin count reprices
-the candidate without re-running anything.
+~100× the ESC γ per flop on this backend).
 
 Coefficient defaults are priors fitted once against the checked-in
 ``BENCH_local_kernels.json`` / ``BENCH_summa3d.json`` rows (CPU backend);
@@ -56,7 +53,6 @@ class CostCoefficients:
     beta_ms_per_byte: float = 1e-6  # inverse bandwidth (β)
     gamma_esc_ms: float = 8.109 / 61581  # per flop (local_kernels esc row)
     gamma_hash_ms: float = 2.73e-2  # per flop (fused-step hash, per-chunk)
-    gamma_binned_ms: float = 4.6e-5  # per pairing (k-binned extra pass)
     overhead: float = 1.0  # fitted measured/raw factor
 
     def replace(self, **kw) -> "CostCoefficients":
@@ -159,19 +155,6 @@ class CostBreakdown:
                 for k, v in dataclasses.asdict(self).items()}
 
 
-def compute_units(plan, path: str) -> Tuple[float, float]:
-    """(flop-priced units, pairing-priced units) of one whole multiply.
-
-    ESC/hash: every path pays the merge/compress over ``total_flops``
-    partial products. Binned additionally pays the per-batch pairing grid
-    the k-bin plan bounds (``pairings`` is a per-batch capacity product).
-    """
-    pairings = 0.0
-    if path == "binned" and plan.kbin is not None:
-        pairings = float(plan.kbin.pairings) * plan.num_batches
-    return float(plan.total_flops), pairings
-
-
 def predict_cost(
     plan,
     grid_shape: Tuple[int, int, int],
@@ -191,13 +174,9 @@ def predict_cost(
         path = plan.local_path
     nb = plan.num_batches
     vol = comm_volume(grid_shape, nb, nnz_a, nnz_b, plan.total_flops, r_bytes)
-    flop_units, pairing_units = compute_units(plan, path)
-    gamma = {
-        "esc": c.gamma_esc_ms,
-        "binned": c.gamma_esc_ms,  # binned keeps the ESC merge pipeline
-        "hash": c.gamma_hash_ms,
-    }[path]
-    compute_ms = gamma * flop_units + c.gamma_binned_ms * pairing_units
+    # both paths pay the merge/compress over every partial product
+    gamma = {"esc": c.gamma_esc_ms, "hash": c.gamma_hash_ms}[path]
+    compute_ms = gamma * float(plan.total_flops)
     dispatch_ms = c.dispatch_ms * nb
     window = max(int(lookahead), 1) if pipelined else 1
     sync_ms = c.sync_ms * nb / window

@@ -9,7 +9,7 @@ import numpy as np
 
 from repro.core import gen
 from repro.core.grid import make_grid
-from repro.core.specs import ExecSpec, PlanSpec
+from repro.core.specs import PlanSpec
 from repro.sparse_apps.graph_algorithms import (
     overlap_pairs,
     overlap_pairs_host,
@@ -296,7 +296,7 @@ def case_masked_multibatch_grid():
     only nontrivial when num_batches > 1 AND layers > 1 (the batch slice is
     fiber-gathered with per-layer column offsets): exact parity with the
     dense reference at nb ∈ {2, 4} × {strict, complement} on the 2x2x2
-    grid, including the k-binned local multiply."""
+    grid, on both local multiplies (ESC and the hash accumulator)."""
     import jax.numpy as jnp
 
     from repro.core.batched import batched_summa3d
@@ -321,7 +321,7 @@ def case_masked_multibatch_grid():
     )
     for complement in (False, True):
         for nb in (2, 4):
-            for binned in ("auto", True, False):
+            for local_path in ("auto", "esc", "hash"):
                 got = np.zeros((n, n), np.float32)
 
                 def consumer(bi, c, cm):
@@ -332,14 +332,14 @@ def case_masked_multibatch_grid():
                     A, B, grid, per_process_memory=1 << 26,
                     consumer=consumer, path="sparse",
                     spec=PlanSpec(force_num_batches=nb, mask=M,
-                                  mask_complement=complement),
-                    exec_spec=ExecSpec(binned=binned),
+                                  mask_complement=complement,
+                                  local_path=local_path),
                 )
                 keep = ~mask_dense if complement else mask_dense
                 np.testing.assert_allclose(
                     got, (xa @ xb) * keep, rtol=1e-4, atol=1e-4,
                 )
-                assert res.num_retries == 0, (complement, nb, binned)
+                assert res.num_retries == 0, (complement, nb, local_path)
     print("OK masked_multibatch_grid")
 
 

@@ -5,7 +5,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from repro.compat import AxisType, make_mesh, set_mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.configs import ARCHS, get_config
 from repro.models import transformer as tfm
@@ -16,7 +16,7 @@ pytestmark = pytest.mark.slow
 
 def tiny_mesh():
     dev = np.array(jax.devices()[:1]).reshape(1, 1)
-    return make_mesh(dev, ("data", "model"),
+    return Mesh(dev, ("data", "model"),
                              axis_types=(AxisType.Auto,) * 2)
 
 
@@ -36,7 +36,7 @@ def test_forward_shapes_and_finite(arch):
     params = tfm.init_params(cfg, jax.random.PRNGKey(42))
     inputs, _ = make_inputs(cfg)
     mesh = tiny_mesh()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits, aux = tfm.forward(cfg, params, inputs, mesh)
     # forward returns Megatron-padded-vocab logits with the pad masked out
     assert logits.shape == (2, 16, cfg.padded_vocab)
@@ -57,7 +57,7 @@ def test_train_grad_step(arch):
     def loss_fn(p):
         return tfm.lm_loss(cfg, p, inputs, targets, mesh)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         loss, grads = jax.value_and_grad(loss_fn)(params)
     assert np.isfinite(float(loss)) and float(loss) > 0
     flat = jax.tree.leaves(grads)
@@ -78,7 +78,7 @@ def test_decode_step_matches_cache_semantics(arch):
         tok = jax.random.randint(jax.random.PRNGKey(2), (B, 1), 0, cfg.vocab)
     else:
         tok = jax.random.normal(jax.random.PRNGKey(2), (B, 1, cfg.d_model))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits, new_cache = tfm.decode_step(
             cfg, params, cache, tok, jnp.int32(0), mesh
         )
@@ -105,7 +105,7 @@ def test_prefill_then_decode_consistent(arch):
     else:
         seq = jax.random.normal(jax.random.PRNGKey(3), (B, S + 1, cfg.d_model))
         prompt, nxt = seq[:, :S], seq[:, S:]
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits_full, _ = tfm.forward(cfg, params, seq, mesh)
         _, cache = tfm.prefill(cfg, params, prompt, s_max=S + 4, mesh=mesh)
         logits_dec, _ = tfm.decode_step(cfg, params, cache, nxt, jnp.int32(S), mesh)
@@ -143,7 +143,7 @@ def test_moe_spgemm_dispatch_equals_scatter():
     cfg_scatter = dc.replace(
         cfg, moe=dc.replace(cfg.moe, dispatch_mode="scatter")
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         l1, _ = tfm.forward(cfg, params, inputs, mesh)
         l2, _ = tfm.forward(cfg_scatter, params, inputs, mesh)
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), rtol=1e-4, atol=1e-4)

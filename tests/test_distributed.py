@@ -23,7 +23,6 @@ CASES = [
     "semiring_or_and",
     "overflow_retry",
     "pipelined_serial_parity",
-    "binned_sparse_path",
     "pipelined_overflow_retry",
     "rectangular_aat",
     "ring_schedule_matches",

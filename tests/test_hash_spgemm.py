@@ -88,21 +88,28 @@ SEMIRINGS = [sr.PLUS_TIMES, sr.MIN_PLUS, sr.MAX_TIMES]
 # Kernel level: insert rounds
 # ---------------------------------------------------------------------------
 class TestHashInsert:
-    def test_pallas_matches_oracle(self):
+    def test_insert_matches_dict_reference(self):
+        """With probe rounds to spare, the table holds exactly the distinct
+        valid keys, each with the sum of its values."""
         rng = np.random.default_rng(0)
-        keys = jnp.asarray(rng.integers(0, 500, 128), jnp.int32)
-        vals = jnp.asarray(rng.uniform(0.5, 1, 128), jnp.float32)
-        valid = jnp.asarray(rng.random(128) < 0.8)
+        keys = rng.integers(0, 500, 128).astype(np.int32)
+        vals = rng.uniform(0.5, 1, 128).astype(np.float32)
+        valid = rng.random(128) < 0.8
         T = 256
-        tk0 = jnp.full((T,), hashkern.EMPTY, jnp.int32)
-        tv0 = jnp.zeros((T,), jnp.float32)
-        ref = hashkern.hash_insert_ref(
-            tk0, tv0, keys, vals, valid, add_kind="sum", max_probes=T)
-        pal = hashkern.hash_insert_pallas(
-            tk0, tv0, keys, vals, valid, add_kind="sum", max_probes=T,
-            interpret=True)
-        for r, p in zip(ref, pal):
-            np.testing.assert_array_equal(np.asarray(r), np.asarray(p))
+        tk, tv, dropped = hashkern.hash_insert(
+            jnp.full((T,), hashkern.EMPTY, jnp.int32),
+            jnp.zeros((T,), jnp.float32), jnp.asarray(keys), jnp.asarray(vals),
+            jnp.asarray(valid), add_kind="sum", max_probes=T)
+        assert int(dropped) == 0
+        want = {}
+        for k, v in zip(keys[valid], vals[valid]):
+            want[int(k)] = want.get(int(k), 0.0) + float(v)
+        tk_np, tv_np = np.asarray(tk), np.asarray(tv)
+        occupied = tk_np != hashkern.EMPTY
+        got = dict(zip(tk_np[occupied].tolist(), tv_np[occupied].tolist()))
+        assert got.keys() == want.keys()
+        for k, v in want.items():
+            np.testing.assert_allclose(got[k], v, rtol=1e-5)
 
     @pytest.mark.parametrize("add_kind", ["sum", "min", "max"])
     def test_duplicate_keys_accumulate_in_one_slot(self, add_kind):
@@ -118,7 +125,7 @@ class TestHashInsert:
         T = 128  # load factor 0.5 over distinct keys
         tk = jnp.full((T,), hashkern.EMPTY, jnp.int32)
         tv = jnp.full((T,), hashkern.table_init_val(add_kind), jnp.float32)
-        tk, tv, dropped = hashkern.hash_insert_ref(
+        tk, tv, dropped = hashkern.hash_insert(
             tk, tv, jnp.asarray(keys_np), jnp.asarray(vals_np),
             jnp.ones(keys_np.shape[0], bool), add_kind=add_kind,
             max_probes=T)
@@ -142,7 +149,7 @@ class TestHashInsert:
         T = 16
         tk = jnp.full((T,), hashkern.EMPTY, jnp.int32)
         tv = jnp.zeros((T,), jnp.float32)
-        tk, tv, dropped = hashkern.hash_insert_ref(
+        tk, tv, dropped = hashkern.hash_insert(
             tk, tv, keys, vals, valid, add_kind="sum", max_probes=T)
         assert int(dropped) == 64 - T  # every slot claimed, rest counted
         assert int(np.sum(np.asarray(tk) != hashkern.EMPTY)) == T
@@ -221,17 +228,9 @@ class TestSpgemmHashParity:
             a, b, out_cap=2048, table_cap=4096, chunk_cap=8, num_chunks=1)
         assert int(ovf) >= total_flops - 8
 
-    def test_pallas_interpret_matches_oracle_path(self):
-        xa, xb, a, b = _pair(seed=13)
-        kw = _hash_kwargs(a, b)
-        c0, o0 = spgemm_hash(a, b, use_pallas=False, **kw)
-        c1, o1 = spgemm_hash(a, b, use_pallas=True, interpret=True, **kw)
-        assert int(o0) == int(o1) == 0
-        _assert_same_output(c1, c0, rtol=1e-6)
-
 
 # ---------------------------------------------------------------------------
-# Plan: hash memory model and 3-way dispatch
+# Plan: hash memory model and ESC/hash dispatch
 # ---------------------------------------------------------------------------
 class TestHashPlanning:
     def test_hash_mem_model_beats_esc_on_compression(self):
@@ -258,7 +257,7 @@ class TestHashPlanning:
         assert p.local_path == expect
         assert (p.hash_caps is not None) == (p.local_path == "hash")
         # explicit paths are respected verbatim
-        for forced in ("esc", "hash", "binned"):
+        for forced in ("esc", "hash"):
             pf = plan_batches(A, B, grid1, per_process_memory=1 << 26,
                               local_path=forced)
             assert pf.local_path == forced
@@ -324,6 +323,55 @@ def _reference(xa, xb, semiring):
         red = np.minimum if semiring.add_kind == "min" else np.maximum
         acc = np.where(hit, red(acc, prod), acc)
     return acc, (np.inf if semiring.add_kind == "min" else -np.inf)
+
+
+class TestHashKeyFit:
+    """The hash table packs a D tile's (row, col) into one i32 key, so the
+    planner must never hand the hash path a tile whose key overflows."""
+
+    TM = 1 << 20  # a protein network's tile height on one chip
+
+    def _plan(self, local_path, force=None):
+        from repro.core.batched import PlanInputs, plan_from_symbolic
+        from repro.core.specs import PlanFloors, PlanSpec
+        from repro.core.symbolic import SymbolicCounts
+
+        tn = self.TM
+        # a strict mask keeps 10 of ~100 partial products per column: the
+        # compression estimate (10) clears HASH_CF_THRESHOLD
+        counts = SymbolicCounts(
+            percol=np.full((1, 1, 1, tn), 100, np.int64),
+            b_colcounts=np.full((1, 1, 1, tn), 10, np.int64),
+            mask_colcounts=np.full((1, 1, 1, tn), 10, np.int64),
+        )
+        inputs = PlanInputs(
+            tm_a=self.TM, max_nnz_a=10 * tn, max_nnz_b=10 * tn,
+            nnz_a=10 * tn, nnz_b=10 * tn, cap_a=10 * tn, cap_b=10 * tn,
+            p=1, cap_mask=10 * tn,
+        )
+        return plan_from_symbolic(
+            counts, inputs, 1 << 40,
+            PlanSpec(local_path=local_path, force_num_batches=force),
+            PlanFloors(),
+        )
+
+    def test_auto_takes_esc_when_key_overflows(self):
+        plan = self._plan("auto")
+        assert plan.compression_est >= HASH_CF_THRESHOLD
+        assert plan.local_path == "esc" and plan.hash_caps is None
+        assert "overflows i32" in plan.path_reason, plan.path_reason
+
+    def test_forced_hash_raises_batches_until_key_fits(self):
+        plan = self._plan("hash")
+        wb = self.TM // plan.num_batches
+        assert plan.local_path == "hash"
+        assert sortkeys.fits_i32(self.TM, wb)
+        assert not sortkeys.fits_i32(self.TM, 2 * wb)  # the smallest such b
+        assert "raised" in plan.path_reason, plan.path_reason
+
+    def test_forced_hash_with_forced_batches_refuses_overflow(self):
+        with pytest.raises(ValueError, match="overflows i32"):
+            self._plan("hash", force=2)
 
 
 class TestBatchedHashDriver:

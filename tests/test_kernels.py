@@ -186,6 +186,18 @@ class TestColPruneKernel:
         kept_r = (np.abs(x) >= t_r[None, :])
         np.testing.assert_array_equal(kept_k, kept_r)
 
+    def test_row_blocks_match_single_block(self):
+        """Streaming the column through several row blocks (with a padded
+        last block) gives the bracket of the one-block run, bit for bit."""
+        from repro.kernels.col_prune import col_topk_bounds_pallas
+
+        rng = np.random.default_rng(11)
+        x = jnp.asarray(rng.standard_normal((300, 40)).astype(np.float32))
+        lo1, hi1 = col_topk_bounds_pallas(x, 7, m_blk=512)
+        lo3, hi3 = col_topk_bounds_pallas(x, 7, m_blk=128)
+        np.testing.assert_array_equal(np.asarray(lo1), np.asarray(lo3))
+        np.testing.assert_array_equal(np.asarray(hi1), np.asarray(hi3))
+
     @pytest.mark.parametrize("m,n,k", [(32, 8, 4), (40, 16, 5)])
     def test_parity_with_numpy_topk_including_ties(self, m, n, k):
         """Threshold selection vs exact numpy top-k with REPEATED values.

@@ -3,7 +3,7 @@
 Single-device (1x1x1) coverage of the masked pipeline: the symbolic mask
 counts against a dense reference, the masked plan's capacity ordering
 (incl. the empty-mask and full-mask edges), the fused multiply under strict
-and complement masks across batch counts, and binned/ESC parity behind the
+and complement masks across batch counts, and hash/ESC parity behind the
 plan switch. The 8-device R-MAT parity cases (triangle counting, overlap
 detection) live in ``tests/app_cases.py`` (slow lane).
 """
@@ -47,7 +47,7 @@ def _operands(grid, n=32, seed=0):
     return xa, xb, A, B
 
 
-def _multiply(A, B, grid, nb, mask=None, complement=False, binned="auto"):
+def _multiply(A, B, grid, nb, mask=None, complement=False, local_path="auto"):
     n = B.shape[1]
     got = np.zeros((A.shape[0], n), np.float32)
 
@@ -58,7 +58,7 @@ def _multiply(A, B, grid, nb, mask=None, complement=False, binned="auto"):
     res = batched_summa3d(
         A, B, grid, per_process_memory=1 << 26, consumer=consumer,
         path="sparse", force_num_batches=nb, mask=mask,
-        mask_complement=complement, binned=binned,
+        mask_complement=complement, local_path=local_path,
     )
     return got, res
 
@@ -155,16 +155,18 @@ class TestMaskedMultiply:
         got, _ = _multiply(A, B, grid1, 2, mask=M, complement=True)
         np.testing.assert_allclose(got, xa @ xb, rtol=1e-4, atol=1e-5)
 
-    def test_binned_matches_esc_under_mask(self, grid1, n=32):
+    def test_hash_matches_esc_under_mask(self, grid1, n=32):
         """The masked filter is applied identically by the ESC packed-key
-        intersect and the binned dense-accumulator indicator."""
+        intersect and the hash path's reject-at-insert probe."""
         xa, xb, A, B = _operands(grid1, n, seed=29)
         mask_dense = np.random.default_rng(23).random((n, n)) < 0.2
         M = scatter_to_grid(_mask_coo(mask_dense), grid1, "C")
-        got_esc, _ = _multiply(A, B, grid1, 2, mask=M, binned=False)
-        got_bin, res = _multiply(A, B, grid1, 2, mask=M, binned=True)
-        assert res.binned
-        np.testing.assert_allclose(got_bin, got_esc, rtol=1e-5, atol=1e-6)
+        got_esc, _ = _multiply(A, B, grid1, 2, mask=M, local_path="esc")
+        got_hash, res = _multiply(A, B, grid1, 2, mask=M, local_path="hash")
+        assert res.local_path == "hash"
+        np.testing.assert_allclose(got_hash, got_esc, rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(got_esc, (xa @ xb) * mask_dense,
+                                   rtol=1e-4, atol=1e-5)
 
 
 class TestPow2Rounding:

@@ -164,14 +164,16 @@ class TestPlanReservedBytes:
         a = sp.from_dense(jnp.asarray(x), cap=800)
         A = scatter_to_grid(a, grid1, "A")
         B = scatter_to_grid(a, grid1, "B")
-        base = plan_batches(A, B, grid1, per_process_memory=1 << 16)
+        # the ESC step holds ~5 r-byte records per partial product, so
+        # 256 KB plans a few batches and 64 KB left plans about 16
+        base = plan_batches(A, B, grid1, per_process_memory=1 << 18)
         tight = plan_batches(
-            A, B, grid1, per_process_memory=1 << 16, reserved_bytes=3 << 14
+            A, B, grid1, per_process_memory=1 << 18, reserved_bytes=3 << 16
         )
         assert tight.num_batches > base.num_batches
         with pytest.raises(MemoryError):
             plan_batches(
-                A, B, grid1, per_process_memory=1 << 16, reserved_bytes=1 << 16
+                A, B, grid1, per_process_memory=1 << 18, reserved_bytes=1 << 18
             )
 
 
@@ -196,7 +198,7 @@ class TestFusedStepCompileCount:
     def test_pow2_caps_hit_jit_cache(self, grid1, n=24):
         """ROADMAP MCL follow-up (b): per-iteration capacity drift must NOT
         recompile the fused step. With pow2-quantized, running-max floored
-        capacities (and the k-bin signature pinned after iteration 1) a
+        capacities (and the local path pinned after iteration 1) a
         4-iteration MCL run traces the fused step at most twice — iteration
         1's scattered operands vs. the reassembled operands of iterations
         2+ — and a repeat run of the same loop traces NOTHING."""

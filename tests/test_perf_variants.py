@@ -7,7 +7,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from repro.compat import AxisType, make_mesh, set_mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.configs import get_config
 from repro.models import transformer as tfm
@@ -21,7 +21,7 @@ pytestmark = pytest.mark.slow
 
 def tiny_mesh():
     dev = np.array(jax.devices()[:1]).reshape(1, 1)
-    return make_mesh(dev, ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    return Mesh(dev, ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 class TestHeadPadding:
@@ -47,7 +47,7 @@ class TestHeadPadding:
         params_pad = tfm.init_params(cfg_pad, jax.random.PRNGKey(3))
         tok = jax.random.randint(jax.random.PRNGKey(4), (2, 12), 0, cfg.vocab)
         mesh = tiny_mesh()
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             l0, _ = tfm.forward(cfg, params, tok, mesh)
             l1, _ = tfm.forward(cfg_pad, params_pad, tok, mesh)
         np.testing.assert_allclose(np.asarray(l0), np.asarray(l1),
@@ -74,7 +74,7 @@ class TestStrategies:
         )
         from repro.data import DataConfig, synthetic_batch
 
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step_fn, _, _ = build_train_step(cfg, mesh, tc, global_batch=2)
             params = tfm.init_params(cfg, jax.random.PRNGKey(0))
             if master:
@@ -102,7 +102,7 @@ class TestStrategies:
                           input_mode=cfg.input_mode, d_model=cfg.d_model)
         batch = synthetic_batch(dcfg, 0)
         losses = {}
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             for strat in ("tp", "dp"):
                 step_fn, _, _ = build_train_step(
                     cfg, mesh, TrainConfig(strategy=strat), global_batch=2

@@ -1,4 +1,4 @@
-"""Packed-key sort engine + k-binned pairing: parity vs the legacy lexsort
+"""Packed-key sort engine: parity vs the legacy lexsort
 path (randomized, over PLUS_TIMES / MIN_PLUS / MAX_TIMES), merge overflow
 reporting, the segmented sorted merge, and the bitonic Pallas kernel."""
 import numpy as np
@@ -7,13 +7,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core import gen
 from repro.core import local_spgemm as lsp
 from repro.core import semiring as sr
 from repro.core import sortkeys as sk
 from repro.core import sparse as sp
-from repro.core import symbolic as sym
-from repro.kernels import ops
 from repro.kernels import sort_engine as se
 from repro.testing import given, settings, strategies as st
 
@@ -180,7 +177,9 @@ class TestMergeSparse:
         across engines and the sorted merge (satellite: overflow reporting)."""
         rng = np.random.default_rng(seed)
         xs = [dense_random(rng, 9, 9, 0.5) for _ in range(parts)]
-        mats = [sp.from_dense(jnp.asarray(x), cap=50) for x in xs]
+        # cap = 9·9 holds every entry: a smaller cap truncates the input and
+        # breaks the expected count below
+        mats = [sp.from_dense(jnp.asarray(x), cap=81) for x in xs]
         distinct = int((sum((x != 0).astype(np.int64) for x in xs) != 0).sum())
         out_cap = max(distinct // 2, 1)
         expect_ovf = distinct - out_cap
@@ -204,6 +203,39 @@ class TestMergeSparse:
                 + np.asarray(merged.cols[: out_cap])
             )
             assert np.all(np.diff(keys) > 0), kwargs
+
+    @pytest.mark.parametrize("local_path", ["esc", "hash"])
+    def test_one_layer_grid_sorted_merge_matches_unsorted(self, local_path):
+        """On a 1x1x1 grid the batched product is the same whether
+        Merge-Fiber takes the sorted single piece or re-merges it."""
+        from repro.core import gen
+        from repro.core.batched import batched_summa3d
+        from repro.core.distsparse import gather_to_global, scatter_to_grid
+        from repro.core.grid import make_grid
+        from repro.core.specs import ExecSpec, PlanSpec
+
+        grid = make_grid(1, 1, 1)
+        a = gen.protein_similarity_like(256, blocks=4, intra_p=0.3, seed=2)
+        A = scatter_to_grid(a, grid, "A")
+        B = scatter_to_grid(a, grid, "B")
+        got = {}
+        for sorted_merge in (True, False):
+            outs = []
+            batched_summa3d(
+                A, B, grid, 1 << 30,
+                consumer=lambda bi, c, cm: outs.append(gather_to_global(c)),
+                spec=PlanSpec(local_path=local_path, force_num_batches=2),
+                exec_spec=ExecSpec(sorted_merge=sorted_merge),
+            )
+            got[sorted_merge] = [
+                tuple(np.asarray(x)[: int(c.nnz)]
+                      for x in (c.rows, c.cols, c.vals))
+                for c in outs
+            ]
+        assert len(got[True]) == 2
+        for mine, theirs in zip(got[True], got[False]):
+            for x, y in zip(mine, theirs):
+                np.testing.assert_array_equal(x, y)
 
     def test_merge_empty_parts(self):
         parts = [sp.empty((6, 6), cap=8) for _ in range(3)]
@@ -236,61 +268,3 @@ class TestBitonicKernel:
         vals = jnp.asarray(rng.random(n).astype(np.float32))
         k1, _ = se.sort_pairs(keys, vals, use_pallas=True, interpret=True)
         assert np.all(np.diff(np.asarray(k1)) >= 0)
-
-
-# ---------------------------------------------------------------------------
-# k-binned pairing
-# ---------------------------------------------------------------------------
-class TestBinnedPairing:
-    def _check(self, a, b):
-        plan = sym.plan_k_bins(
-            np.asarray(a.col_counts()), np.asarray(b.row_counts()), a.cap, b.cap
-        )
-        c_ref = ops.spgemm_paired(a, b)
-        c_bin, ovf = ops.spgemm_paired_binned(
-            a, b, plan.num_bins, plan.bin_cap_a, plan.bin_cap_b,
-            bin_map=jnp.asarray(plan.bin_of_k),
-        )
-        assert int(ovf) == 0
-        np.testing.assert_allclose(
-            np.asarray(c_bin), np.asarray(c_ref), rtol=1e-4, atol=1e-4
-        )
-        return plan
-
-    def test_uniform_workload(self):
-        a = gen.erdos_renyi(64, 5, seed=1)
-        b = gen.erdos_renyi(64, 5, seed=2)
-        plan = self._check(a, b)
-        assert plan.pairings < plan.pairings_unbinned
-
-    def test_skewed_workload_reduces_pairings(self):
-        """The acceptance shape: on skewed-k inputs the balanced-bin plan
-        must still do measurably fewer pairings than O(capA×capB)."""
-        a = gen.rmat(scale=6, edge_factor=6, seed=3)
-        b = gen.rmat(scale=6, edge_factor=6, seed=4)
-        plan = self._check(a, b)
-        assert plan.num_bins > 1
-        assert plan.pairings * 2 <= plan.pairings_unbinned
-
-    def test_pallas_interpret_matches(self):
-        a = gen.erdos_renyi(48, 4, seed=5)
-        b = gen.erdos_renyi(48, 4, seed=6)
-        plan = sym.plan_k_bins(
-            np.asarray(a.col_counts()), np.asarray(b.row_counts()), a.cap, b.cap
-        )
-        c_ref = ops.spgemm_paired(a, b)
-        c_p, ovf = ops.spgemm_paired_binned(
-            a, b, plan.num_bins, plan.bin_cap_a, plan.bin_cap_b,
-            bin_map=jnp.asarray(plan.bin_of_k), use_pallas=True, interpret=True,
-        )
-        assert int(ovf) == 0
-        np.testing.assert_allclose(
-            np.asarray(c_p), np.asarray(c_ref), rtol=1e-4, atol=1e-4
-        )
-
-    def test_bin_overflow_reported(self):
-        a = gen.erdos_renyi(64, 5, seed=7)
-        b = gen.erdos_renyi(64, 5, seed=8)
-        _, ovf = ops.spgemm_paired_binned(a, b, num_bins=4, bin_cap_a=8,
-                                          bin_cap_b=8)
-        assert int(ovf) > 0

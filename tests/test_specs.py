@@ -26,7 +26,7 @@ from repro.core.specs import (
     PlanSpec,
     resolve_specs,
 )
-from repro.core.summa3d import BatchCaps, BinnedCaps, HashCaps
+from repro.core.summa3d import BatchCaps, HashCaps
 from repro.runtime.driver import LookaheadWindow
 
 
@@ -157,18 +157,11 @@ class TestPlanFloors:
         b = PlanFloors(caps=BatchCaps(1, 2, 3, 4))
         m = a.merged(b)
         assert m.caps == BatchCaps(1, 2, 3, 4) and m.sel_cap == 3
-        assert m.kbin_caps is None and m.hash_caps is None
-
-    def test_merged_bin_count_mismatch_raises(self):
-        a = PlanFloors(kbin_caps=BinnedCaps(4, 64, 64))
-        b = PlanFloors(kbin_caps=BinnedCaps(8, 64, 64))
-        with pytest.raises(ValueError, match="bin counts"):
-            a.merged(b)
+        assert m.hash_caps is None
 
     def test_meta_round_trip(self):
         f = PlanFloors(caps=BatchCaps(8, 16, 32, 64), sel_cap=7,
-                       num_batches=3, kbin_caps=BinnedCaps(4, 8, 8),
-                       hash_caps=HashCaps(32, 16, 4), caps_pow2=True)
+                       num_batches=3, hash_caps=HashCaps(32, 16, 4), caps_pow2=True)
         assert PlanFloors.from_meta(f.to_meta()) == f
         assert PlanFloors.from_meta(None) == PlanFloors()
         assert PlanFloors.from_meta({}) == PlanFloors()

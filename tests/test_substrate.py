@@ -7,7 +7,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from repro.compat import AxisType, make_mesh, set_mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.checkpoint import store
 from repro.configs import get_config
@@ -21,7 +21,7 @@ from repro.train import TrainConfig, build_train_step
 
 def tiny_mesh():
     dev = np.array(jax.devices()[:1]).reshape(1, 1)
-    return make_mesh(dev, ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    return Mesh(dev, ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 class TestOptimizer:
@@ -70,7 +70,7 @@ class TestTrainStep:
     def test_loss_decreases_smoke_model(self):
         cfg = get_config("granite-20b", smoke=True)
         mesh = tiny_mesh()
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step_fn, sh, _ = build_train_step(cfg, mesh, TrainConfig(
                 optimizer=adamw.AdamWConfig(lr=3e-3, warmup_steps=5)))
             params = tfm.init_params(cfg, jax.random.PRNGKey(0))
@@ -89,7 +89,7 @@ class TestTrainStep:
         mesh = tiny_mesh()
         dcfg = DataConfig(seq_len=8, global_batch=4, vocab=cfg.vocab)
         batch = synthetic_batch(dcfg, 0)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             f1, _, _ = build_train_step(cfg, mesh, TrainConfig(microbatches=1))
             f2, _, _ = build_train_step(cfg, mesh, TrainConfig(microbatches=2))
             # step fns donate their inputs — build fresh states per call
@@ -158,7 +158,7 @@ class TestFaultTolerance:
             return {"params": params, "opt": adamw.init_opt_state(params)}
 
         def wrapped_step(state, batch):
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 p, o, m = step_fn(state["params"], state["opt"], batch)
             return {"params": p, "opt": o}, m
 
@@ -211,7 +211,7 @@ class TestServeEngine:
     def test_continuous_batching_completes_all(self):
         cfg = get_config("granite-20b", smoke=True)
         mesh = tiny_mesh()
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = tfm.init_params(cfg, jax.random.PRNGKey(1))
             eng = ServeEngine(cfg, params, mesh,
                               EngineConfig(max_batch=2, s_max=32))
