@@ -49,7 +49,6 @@ def run_all() -> None:
         bench_layers_batches,
         bench_local_kernels,
         bench_mcl,
-        bench_roofline,
         bench_scaling,
         bench_serve,
         bench_summa3d,
@@ -66,7 +65,6 @@ def run_all() -> None:
     bench_mcl.run()             # Fig. 3 (HipMCL end-to-end)
     bench_graph.run()           # §V-B masked graph workloads
     bench_serve.run()           # plan-cached serving engine
-    bench_roofline.run()        # EXPERIMENTS.md section Roofline feed
 
 
 def _write_suite(suite: str, rows_fn, json_path: pathlib.Path) -> None:

@@ -40,6 +40,19 @@ falls back to the synchronous retry loop for that batch — selection capacity
 grows first, then the multiply capacities (2× per attempt) — bounded, logged,
 and tested. ``pipelined=False`` keeps the fully synchronous schedule (one
 host round-trip per batch), which doubles as the benchmark baseline.
+
+Host spans (``core/spans.py``; recorded only under ``jax.profiler.trace``):
+``spgemm.call`` around one ``batched_summa3d`` call; ``spgemm.plan`` around
+``plan_batches`` with its children ``spgemm.symbolic`` (the device pass and
+the counts' copy to the host) and ``spgemm.plan_host`` (operand facts and
+``plan_from_symbolic``); ``spgemm.ladder_ceiling`` (the retry ladder's
+memory ceiling); per batch ``spgemm.dispatch`` (``batch=``),
+``spgemm.wait`` (the host blocks on the overflow flags) and
+``spgemm.consume`` (the consumer call); ``spgemm.retry`` (``batch=``,
+``kind=sel|mul``), one per count in ``RunReport.retries``, around the
+capacity growth; and ``spgemm.replan`` (``batch=``, ``split=``) around each
+finer sub-plan of a degraded batch. A retried or degraded batch's own
+dispatches and waits follow those two spans.
 """
 from __future__ import annotations
 
@@ -66,6 +79,7 @@ from .summa3d import (
 )
 from .placement import BLOCK_CYCLIC, Placement
 from .sparse import hstack_remap
+from .spans import span
 from .specs import ExecSpec, PlanFloors, PlanSpec, resolve_specs
 from .symbolic import (
     HASH_LOAD_FACTOR,
@@ -335,20 +349,25 @@ def plan_batches(
         spec, floors, None, legacy, default_local_path="esc",
         where="plan_batches", allow_exec=False,
     )
-    counts = symbolic3d_counts(a, b, grid, mask=spec.mask)
-    inputs = PlanInputs(
-        tm_a=a.tile_shape[0],
-        max_nnz_a=int(np.asarray(a.nnz).max()),
-        max_nnz_b=int(np.asarray(b.nnz).max()),
-        nnz_a=int(np.asarray(a.nnz).sum()),
-        nnz_b=int(np.asarray(b.nnz).sum()),
-        cap_a=a.cap,
-        cap_b=b.cap,
-        p=grid.p,
-        cap_mask=spec.mask.cap if spec.mask is not None else None,
-        k_dim=a.shape[1],
-    )
-    return plan_from_symbolic(counts, inputs, per_process_memory, spec, floors)
+    with span("spgemm.plan"):
+        with span("spgemm.symbolic"):
+            counts = symbolic3d_counts(a, b, grid, mask=spec.mask)
+        with span("spgemm.plan_host"):
+            inputs = PlanInputs(
+                tm_a=a.tile_shape[0],
+                max_nnz_a=int(np.asarray(a.nnz).max()),
+                max_nnz_b=int(np.asarray(b.nnz).max()),
+                nnz_a=int(np.asarray(a.nnz).sum()),
+                nnz_b=int(np.asarray(b.nnz).sum()),
+                cap_a=a.cap,
+                cap_b=b.cap,
+                p=grid.p,
+                cap_mask=spec.mask.cap if spec.mask is not None else None,
+                k_dim=a.shape[1],
+            )
+            return plan_from_symbolic(
+                counts, inputs, per_process_memory, spec, floors
+            )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -754,6 +773,12 @@ def plan_footprint(
     return r_bytes * (max_nnz_a + max_nnz_b + sel_cap) + inter + reserved_bytes
 
 
+def _retry_kind(ovf: np.ndarray) -> str:
+    """Which capacity an overflow retry grows (``grow`` in
+    ``batched_summa3d``): the selection first, else the multiply."""
+    return "sel" if ovf[0] > 0 else "mul"
+
+
 class _LadderBlocked(Exception):
     """Raised inside the retry ladder when the next cap doubling would blow
     the per-process memory ceiling — caught by the degradation path, which
@@ -908,11 +933,25 @@ def batched_summa3d(
     extent) instead of OOMing. Every retry/replan lands in the structured
     ``BatchedResult.report`` (`RunReport`). ``degrade=False`` restores the
     unbounded ladder.
+
+    The call runs inside the host span ``spgemm.call`` (module docstring).
     """
-    spec, floors, ex = resolve_specs(
-        spec, floors, exec_spec, legacy, default_local_path="auto",
-        where="batched_summa3d",
-    )
+    with span("spgemm.call"):
+        spec, floors, ex = resolve_specs(
+            spec, floors, exec_spec, legacy, default_local_path="auto",
+            where="batched_summa3d",
+        )
+        return _batched_summa3d(
+            a, b, grid, per_process_memory, consumer, path, semiring, spec,
+            floors, ex, postprocess,
+        )
+
+
+def _batched_summa3d(
+    a, b, grid, per_process_memory, consumer, path, semiring, spec, floors,
+    ex, postprocess,
+) -> BatchedResult:
+    """``batched_summa3d`` with its specs resolved, inside its span."""
     placement = spec.placement
     if placement is not None and not isinstance(placement, Placement):
         raise ValueError(
@@ -962,8 +1001,9 @@ def batched_summa3d(
     # (`plan_footprint`): a plan is allowed to exceed the strict budget
     # (slack and uncharged scratch make that routine at tight budgets), but
     # the ladder may never grow beyond whichever is larger.
-    max_nnz_a = int(np.asarray(a.nnz).max())
-    max_nnz_b = int(np.asarray(b.nnz).max())
+    with span("spgemm.ladder_ceiling"):
+        max_nnz_a = int(np.asarray(a.nnz).max())
+        max_nnz_b = int(np.asarray(b.nnz).max())
 
     def _footprint(caps_: BatchCaps, sel_cap_: int, hc_) -> int:
         return plan_footprint(
@@ -977,12 +1017,18 @@ def batched_summa3d(
         bi: int, caps_: BatchCaps, sel_cap_: int, hc_, mask_cap_: int
     ):
         """Async-dispatch one fused batch step; nothing blocks here."""
-        return _fused_jit(
-            a, b, jnp.int32(bi), mask, grid=grid, num_batches=nb,
-            sel_cap=sel_cap_, caps=caps_, semiring=semiring,
-            sorted_merge=sorted_merge, path=path, hashc=hc_,
-            mask_cap=mask_cap_, mask_complement=mask_complement,
-        )
+        with span("spgemm.dispatch", batch=bi):
+            return _fused_jit(
+                a, b, jnp.int32(bi), mask, grid=grid, num_batches=nb,
+                sel_cap=sel_cap_, caps=caps_, semiring=semiring,
+                sorted_merge=sorted_merge, path=path, hashc=hc_,
+                mask_cap=mask_cap_, mask_complement=mask_complement,
+            )
+
+    def wait(bi: int, ovf) -> np.ndarray:
+        """The host's sync point: batch bi's overflow flags, read back."""
+        with span("spgemm.wait", batch=bi):
+            return np.asarray(ovf)
 
     # capacities actually used, including retry growth — reported on the
     # returned plan so iterated callers (MCL) floor their NEXT plan on
@@ -1049,13 +1095,14 @@ def batched_summa3d(
         dispatch_fn = dispatch_fn or dispatch
         for _ in range(max_retries + 1):
             c_batch, ovf = dispatch_fn(bi, caps_, sel_cap_, hc_, mask_cap_)
-            o = np.asarray(ovf)
+            o = wait(bi, ovf)
             if not o.any():
                 return c_batch
             retries += 1
-            caps_, sel_cap_, hc_, mask_cap_ = grow(
-                o, caps_, sel_cap_, hc_, mask_cap_, record=record
-            )
+            with span("spgemm.retry", batch=bi, kind=_retry_kind(o)):
+                caps_, sel_cap_, hc_, mask_cap_ = grow(
+                    o, caps_, sel_cap_, hc_, mask_cap_, record=record
+                )
         raise RuntimeError(
             f"batch {bi}: capacity overflow persisted after {max_retries} retries"
         )
@@ -1074,12 +1121,13 @@ def batched_summa3d(
             try:
                 # a fresh sub-plan: caller floors do not apply (sub-batch
                 # caps live in their own static-signature space)
-                sub = plan_batches(
-                    a, b, grid, per_process_memory,
-                    spec=spec.replace(
-                        local_path=forced, force_num_batches=nb * d,
-                    ),
-                )
+                with span("spgemm.replan", batch=bi, split=d):
+                    sub = plan_batches(
+                        a, b, grid, per_process_memory,
+                        spec=spec.replace(
+                            local_path=forced, force_num_batches=nb * d,
+                        ),
+                    )
             except MemoryError as e:
                 raise RuntimeError(
                     f"batch {bi}: memory ceiling hit and no finer batching "
@@ -1094,13 +1142,14 @@ def batched_summa3d(
             sub_hc = sub.hash_caps if use_hash else None
 
             def sub_dispatch(sj, caps_, sel_cap_, hc_, mask_cap_):
-                return _fused_jit(
-                    a, b, jnp.int32(sj), mask, grid=grid,
-                    num_batches=nb_f, sel_cap=sel_cap_, caps=caps_,
-                    semiring=semiring, sorted_merge=sorted_merge, path=path,
-                    hashc=hc_, mask_cap=mask_cap_,
-                    mask_complement=mask_complement,
-                )
+                with span("spgemm.dispatch", batch=sj):
+                    return _fused_jit(
+                        a, b, jnp.int32(sj), mask, grid=grid,
+                        num_batches=nb_f, sel_cap=sel_cap_, caps=caps_,
+                        semiring=semiring, sorted_merge=sorted_merge,
+                        path=path, hashc=hc_, mask_cap=mask_cap_,
+                        mask_complement=mask_complement,
+                    )
 
             try:
                 parts = [
@@ -1134,23 +1183,26 @@ def batched_summa3d(
         """Apply the device-side hook (async — nothing blocks here)."""
         return postprocess(bi, c_batch) if postprocess is not None else c_batch
 
+    def consume(bi: int, c_post) -> None:
+        with span("spgemm.consume", batch=bi):
+            consumed.append(consumer(bi, c_post, _col_map(bi)))
+
     def finish(bi: int, c_post, ovf) -> None:
         """Sync point: read batch bi's flags, retry if beaten, consume."""
         nonlocal retries
-        o = np.asarray(ovf)
+        o = wait(bi, ovf)
         if o.any():
             retries += 1
             # the speculatively postprocessed batch was built from a garbage
             # product — recompute synchronously and re-run the hook on it
             try:
-                c_batch = run_batch_sync(
-                    bi, *grow(o, caps, sel_cap, hc, mask_cap)
-                )
+                with span("spgemm.retry", batch=bi, kind=_retry_kind(o)):
+                    grown = grow(o, caps, sel_cap, hc, mask_cap)
+                c_batch = run_batch_sync(bi, *grown)
             except _LadderBlocked:
                 c_batch = run_batch_degraded(bi)
             c_post = post(bi, c_batch)
-        col_map = _col_map(bi)
-        consumed.append(consumer(bi, c_post, col_map))
+        consume(bi, c_post)
 
     def _col_map(bi: int) -> np.ndarray:
         col_map = batch_column_map(n_cols, grid, nb, bi)
@@ -1166,7 +1218,7 @@ def batched_summa3d(
             c_batch = post(
                 bi, run_batch_guarded(bi, caps, sel_cap, hc, mask_cap)
             )
-            consumed.append(consumer(bi, c_batch, _col_map(bi)))
+            consume(bi, c_batch)
     else:
         # deferred import: runtime.resilient imports this module (RunReport)
         from ..runtime.driver import LookaheadWindow

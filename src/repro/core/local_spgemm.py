@@ -185,26 +185,35 @@ def spgemm_esc(
     ``mask_complement=False``, C = (A·B) ⊙ ¬M for ``mask_complement=True``.
     Coordinate filtering commutes with the coordinate-wise merge, so this is
     exact for every semiring.
+
+    The three phases run under the named scopes ``spgemm.esc.sort_a`` (A to
+    column-major order), ``spgemm.esc.expand`` (the partial products and the
+    mask filter) and ``spgemm.esc.compress``, which a profiler trace reads.
     """
     m, k = a.shape
     k2, n = b.shape
     assert k == k2
-    a_csc = a if a_is_colsorted else a.sort_colmajor()
+    with jax.named_scope("spgemm.esc.sort_a"):
+        a_csc = a if a_is_colsorted else a.sort_colmajor()
     # B entries as (k, j): transpose so cols hold k, rows hold j
     bt = b.transpose()  # shape (n, k); entries (j, k) with rows=j? No: see below
     # SparseCOO(b).transpose() swaps arrays: rows=old cols (j->k?), careful:
     # b entry is (row=k, col=j). After transpose: row=j, col=k, shape (n, k).
-    rows, cols, vals, valid, total = _expand(a_csc, bt, flops_cap, semiring)
-    flop_overflow = jnp.maximum(total - flops_cap, 0)
-    if mask_keys is not None:
-        key = sortkeys.pack_rowmajor(rows, cols, n)
-        hit = sortkeys.keys_in_sorted(key, mask_keys)
-        valid = valid & (~hit if mask_complement else hit)
+    with jax.named_scope("spgemm.esc.expand"):
+        rows, cols, vals, valid, total = _expand(a_csc, bt, flops_cap, semiring)
+        flop_overflow = jnp.maximum(total - flops_cap, 0)
+        if mask_keys is not None:
+            key = sortkeys.pack_rowmajor(rows, cols, n)
+            hit = sortkeys.keys_in_sorted(key, mask_keys)
+            valid = valid & (~hit if mask_complement else hit)
 
     expanded = SparseCOO(rows, cols, vals, jnp.int32(flops_cap), (m, n))
     # compress: packed-key engine (bucket scan / single-key sort — the one
     # ordering step of the whole pipeline; see repro.core.sortkeys)
-    merged, overflow = _coalesce_semiring(expanded, valid, out_cap, semiring, engine)
+    with jax.named_scope("spgemm.esc.compress"):
+        merged, overflow = _coalesce_semiring(
+            expanded, valid, out_cap, semiring, engine
+        )
     return merged, overflow + flop_overflow
 
 

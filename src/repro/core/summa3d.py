@@ -30,6 +30,13 @@ one executable serves every batch, and the pipelined driver
 (``batched.batched_summa3d``) dispatches batch i+1 while batch i computes,
 reading the device-resident overflow flags only when it drains its window.
 
+Each phase of the per-batch step runs under a ``jax.named_scope`` whose name
+starts with ``spgemm.`` (``spgemm.select``, ``spgemm.gather``, the local
+multiply's ``spgemm.esc.*`` / ``spgemm.hash``, ``spgemm.split``,
+``spgemm.fiber_exchange``, ``spgemm.merge``): the scope lands in every HLO
+op's ``op_name`` and in a profiler trace's ``tf_op``, so a trace splits the
+fused step's device time by phase. Scopes change no computation.
+
 ``reassemble_operands`` closes the loop for iterated multiplies (MCL-style
 A ← f(A·A), §V-C): the batched C outputs are redistributed into fresh A-kind
 and B-kind operands entirely on the grid — the A route is a pure local
@@ -328,21 +335,24 @@ def _sparse_tile_body(
     tm_a, _ = a_loc.shape
     _, tn_b = b_loc.shape
     piece_w = tn_b // l
-    a_cat = _gather_A(a_loc)
-    b_cat = _gather_B(b_loc)
+    with jax.named_scope("spgemm.gather"):
+        a_cat = _gather_A(a_loc)
+        b_cat = _gather_B(b_loc)
     mkeys = None
     if mask is not None:
-        mkeys = sortkeys.sorted_mask_keys(
-            mask.rows, mask.cols, mask.valid_mask(), (tm_a, tn_b)
-        )
+        with jax.named_scope("spgemm.select"):
+            mkeys = sortkeys.sorted_mask_keys(
+                mask.rows, mask.cols, mask.valid_mask(), (tm_a, tn_b)
+            )
     if hashc is not None:
-        d_tile, ovf_mul = spgemm_hash(
-            a_cat, b_cat, out_cap=caps.d_cap,
-            table_cap=hashc.table_cap, chunk_cap=hashc.chunk_cap,
-            num_chunks=hashc.num_chunks, semiring=semiring,
-            mask_keys=mkeys, mask_complement=mask_complement,
-            max_probes=hashc.max_probes,
-        )  # (tm, tn_b) sparse, row-major sorted
+        with jax.named_scope("spgemm.hash"):
+            d_tile, ovf_mul = spgemm_hash(
+                a_cat, b_cat, out_cap=caps.d_cap,
+                table_cap=hashc.table_cap, chunk_cap=hashc.chunk_cap,
+                num_chunks=hashc.num_chunks, semiring=semiring,
+                mask_keys=mkeys, mask_complement=mask_complement,
+                max_probes=hashc.max_probes,
+            )  # (tm, tn_b) sparse, row-major sorted
     else:
         d_tile, ovf_mul = spgemm_esc(
             a_cat, b_cat, out_cap=caps.d_cap, flops_cap=caps.flops_cap,
@@ -355,24 +365,27 @@ def _sparse_tile_body(
     else:
         # ColSplit (Alg. 2 line 4): one partitioned split into all l
         # pieces, order-preserving (pieces stay row-major sorted)
-        pr_, pc_, pv_, pn_, ovf_split = d_tile.split_col_blocks(
-            l, caps.piece_cap
-        )
+        with jax.named_scope("spgemm.split"):
+            pr_, pc_, pv_, pn_, ovf_split = d_tile.split_col_blocks(
+                l, caps.piece_cap
+            )
         # AllToAll-Fiber (Alg. 2 line 5)
-        pr_ = lax.all_to_all(pr_, LAYER_AX, split_axis=0, concat_axis=0)
-        pc_ = lax.all_to_all(pc_, LAYER_AX, split_axis=0, concat_axis=0)
-        pv_ = lax.all_to_all(pv_, LAYER_AX, split_axis=0, concat_axis=0)
-        pn_ = lax.all_to_all(
-            pn_[:, None], LAYER_AX, split_axis=0, concat_axis=0
-        )[:, 0]
+        with jax.named_scope("spgemm.fiber_exchange"):
+            pr_ = lax.all_to_all(pr_, LAYER_AX, split_axis=0, concat_axis=0)
+            pc_ = lax.all_to_all(pc_, LAYER_AX, split_axis=0, concat_axis=0)
+            pv_ = lax.all_to_all(pv_, LAYER_AX, split_axis=0, concat_axis=0)
+            pn_ = lax.all_to_all(
+                pn_[:, None], LAYER_AX, split_axis=0, concat_axis=0
+            )[:, 0]
         parts = [
             SparseCOO(pr_[k], pc_[k], pv_[k], pn_[k], (tm_a, piece_w))
             for k in range(l)
         ]
     # Merge-Fiber (Alg. 2 line 6): sort-free merge of the l pieces
-    c_tile, ovf_merge = merge_sparse(
-        parts, caps.c_cap, semiring, assume_sorted=sorted_merge
-    )
+    with jax.named_scope("spgemm.merge"):
+        c_tile, ovf_merge = merge_sparse(
+            parts, caps.c_cap, semiring, assume_sorted=sorted_merge
+        )
     return c_tile, ovf_mul + ovf_split + ovf_merge
 
 
@@ -500,36 +513,38 @@ def summa3d_fused_step(
         mask_t = rest.pop(0) if mask is not None else None
         a_loc = _squeeze_tile(a_t)
         b_loc = _squeeze_tile(b_t)
-        # Batch-Select (Alg. 4 line 5): block-cyclic column selection
-        sel, ovf_sel = b_loc.select_cols_blockcyclic(
-            batch_, num_batches, l, new_cap=sel_cap
-        )
-        ovf_sel = _pmax_grid(ovf_sel)
-        mask_cat, ovf_mask = None, jnp.int32(0)
-        if mask_t is not None:
-            # Mask-Select: slice this batch's columns out of the local mask
-            # tile, then gather the l layer pieces along the fiber — layer t
-            # owns D columns [t*wbl, (t+1)*wbl) of the selected batch.
-            m_loc = _squeeze_tile(mask_t)
-            msel, ovf_mask = m_loc.select_col_block(
-                batch_ * wbl, wbl, new_cap=mask_cap
+        with jax.named_scope("spgemm.select"):
+            # Batch-Select (Alg. 4 line 5): block-cyclic column selection
+            sel, ovf_sel = b_loc.select_cols_blockcyclic(
+                batch_, num_batches, l, new_cap=sel_cap
             )
-            ovf_mask = _pmax_grid(ovf_mask)
-            k_ax = lax.axis_index(LAYER_AX)
-            mv = msel.valid_mask()
-            mrows = jnp.where(mv, msel.rows, tm_a)
-            mcols = jnp.where(mv, k_ax * wbl + msel.cols, wb)
-            g_mr = lax.all_gather(mrows, LAYER_AX).reshape(-1)
-            g_mc = lax.all_gather(mcols, LAYER_AX).reshape(-1)
-            gcap = g_mr.shape[0]
-            # all slots declared live; padding is sentinel-coded (tm, wb)
-            mask_cat = SparseCOO(
-                g_mr, g_mc, jnp.ones((gcap,), jnp.float32),
-                jnp.int32(gcap), (tm_a, wb),
-            )
+            ovf_sel = _pmax_grid(ovf_sel)
+            mask_cat, ovf_mask = None, jnp.int32(0)
+            if mask_t is not None:
+                # Mask-Select: slice this batch's columns out of the local
+                # mask tile, then gather the l layer pieces along the fiber
+                # — layer t owns D columns [t*wbl, (t+1)*wbl) of the batch.
+                m_loc = _squeeze_tile(mask_t)
+                msel, ovf_mask = m_loc.select_col_block(
+                    batch_ * wbl, wbl, new_cap=mask_cap
+                )
+                ovf_mask = _pmax_grid(ovf_mask)
+                k_ax = lax.axis_index(LAYER_AX)
+                mv = msel.valid_mask()
+                mrows = jnp.where(mv, msel.rows, tm_a)
+                mcols = jnp.where(mv, k_ax * wbl + msel.cols, wb)
+                g_mr = lax.all_gather(mrows, LAYER_AX).reshape(-1)
+                g_mc = lax.all_gather(mcols, LAYER_AX).reshape(-1)
+                gcap = g_mr.shape[0]
+                # all slots declared live; padding is sentinel-coded (tm, wb)
+                mask_cat = SparseCOO(
+                    g_mr, g_mc, jnp.ones((gcap,), jnp.float32),
+                    jnp.int32(gcap), (tm_a, wb),
+                )
         if path == "dense":
-            a_cat = _gather_A(a_loc)
-            b_cat = _gather_B(sel)
+            with jax.named_scope("spgemm.gather"):
+                a_cat = _gather_A(a_loc)
+                b_cat = _gather_B(sel)
             d_tile = spmm(a_cat, b_cat.to_dense(), semiring)
             if mask_cat is not None:
                 d_tile = jnp.where(

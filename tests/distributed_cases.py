@@ -531,6 +531,35 @@ def case_rect_grid_oracle_parity():
     print("OK rect_grid_oracle_parity")
 
 
+def case_fused_step_phase_scopes():
+    """On a layered grid (l = 2) every gather, scatter, sort and reduce of
+    the compiled fused step lies under one phase scope, and the fiber
+    all-to-alls under ``spgemm.fiber_exchange``."""
+    from phase_scopes import checked_ops, hlo_text, outside_one_phase, scopes
+
+    from repro.core.batched import _fused_jit
+
+    grid = make_grid(2, 2, 2)
+    _, a = _rand_square(32, 0.2, seed=71)
+    A = scatter_to_grid(a, grid, "A")
+    B = scatter_to_grid(a, grid, "B")
+    for local_path in ("esc", "hash"):
+        plan = plan_batches(A, B, grid, 1 << 26, spec=PlanSpec(
+            local_path=local_path, force_num_batches=2))
+        hlo = hlo_text(_fused_jit.lower(
+            A, B, jnp.int32(0), None, grid=grid, num_batches=2,
+            sel_cap=plan.sel_cap, caps=plan.caps, semiring=sr.PLUS_TIMES,
+            sorted_merge=True, path="sparse", hashc=plan.hash_caps,
+            mask_cap=0, mask_complement=False,
+        ))
+        assert outside_one_phase(hlo) == [], local_path
+        found = {scopes(name)[0] for _, name in checked_ops(hlo)}
+        assert {"spgemm.select", "spgemm.split", "spgemm.merge"} <= found
+        a2a = [line for line in hlo.splitlines() if " all-to-all(" in line]
+        assert a2a and all("/spgemm.fiber_exchange/" in line for line in a2a)
+    print("OK fused_step_phase_scopes")
+
+
 def _collect_cases():
     return {
         name[len("case_"):]: fn

@@ -28,6 +28,7 @@ CASES = [
     "ring_schedule_matches",
     "tune_oracle_parity",
     "rect_grid_oracle_parity",
+    "fused_step_phase_scopes",
 ]
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
