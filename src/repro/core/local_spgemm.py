@@ -225,7 +225,8 @@ def _coalesce_semiring(
 
     Dispatches to the packed-key engine (``repro.core.sortkeys``): sort-free
     bucket scan for small key spaces, single-key packed sort otherwise,
-    ``engine="lexsort"`` for the seed's two-key reference path.
+    ``engine="lexsort"`` for the two-key sort carrying the values (no
+    permutation gathers; key spaces past i32).
     """
     m, n = x.shape
     rows, cols, vals, nnz, overflow = sortkeys.coalesce_entries(
@@ -427,7 +428,8 @@ def local_symbolic_exact(
 
     The distinct-coordinate count runs on the packed-key engine: the bucket
     scan needs no sort at all, the packed fallback sorts one bare key array
-    (no payload) — either way, never a two-key lexsort.
+    (no payload), and key spaces past i32 sort the bare (row, col) pair on
+    two keys.
     """
     m, _ = a.shape
     _, n = b.shape

@@ -20,9 +20,11 @@ trace time:
   * ``"packed"``  — one single-key ``lax.sort`` carrying the values, then a
     linear boundary scan. O(cap log cap) but with a one-word comparator and no
     permutation gathers; the fallback when the key space is too large to scan.
-  * ``"lexsort"`` — the seed's two-key lexsort path, kept verbatim as the
-    reference for parity tests and for shapes whose packed key would overflow
-    i32 (x64 is disabled under jax defaults).
+  * ``"lexsort"`` — a two-key sort carrying the values, no permutation
+    gathers: one stable ``lax.sort`` on (row, col) that moves the values with
+    the keys (``sort_two_keys``), then the same boundary scan. For shapes
+    whose packed key would overflow i32 (x64 is disabled under jax defaults),
+    and the reference the other engines are tested against.
 
 ``choose_engine`` implements the auto policy; all entry points accept an
 ``engine=`` override so benchmarks and tests can pin a path.
@@ -73,6 +75,14 @@ def pack_colmajor(rows: Array, cols: Array, m: int) -> Array:
 
 def unpack_colmajor(key: Array, m: int) -> Tuple[Array, Array]:
     return key % (m + 1), key // (m + 1)
+
+
+def sort_two_keys(primary: Array, secondary: Array, *payloads: Array):
+    """Stable sort by (``primary``, ``secondary``) that carries ``payloads``
+    along: returns (primary, secondary, *payloads), all sorted. One
+    ``lax.sort`` with two keys; no permutation is built and gathered by, so
+    equal keys keep their input order exactly as a lexsort plus gathers."""
+    return jax.lax.sort((primary, secondary, *payloads), num_keys=2, is_stable=True)
 
 
 def choose_engine(m: int, n: int, cap: int, engine: str = "auto") -> str:
@@ -163,13 +173,12 @@ def _coalesce_bucket(key, valid, vals, nbuckets, sent, new_cap, add_kind):
 
 
 def _coalesce_lexsort(rows, cols, vals, valid, m, n, new_cap, add_kind):
-    """The seed's two-key path, preserved as the parity reference."""
+    """Two-key sort carrying the values, no permutation gathers: (row, col)
+    keys for shapes whose packed key overflows i32, then the boundary scan."""
     cap = rows.shape[0]
     rows = jnp.where(valid, rows, m)
     cols = jnp.where(valid, cols, n)
-    order = jnp.lexsort((cols, rows))
-    rows, cols = rows[order], cols[order]
-    vals = vals[order]
+    rows, cols, vals = sort_two_keys(rows, cols, vals)
     vmask = rows < m
     new_key = jnp.ones((cap,), dtype=bool)
     if cap > 1:
@@ -235,8 +244,7 @@ def count_unique(
     if eng == "lexsort":
         r = jnp.where(valid, rows, m)
         c = jnp.where(valid, cols, n)
-        order = jnp.lexsort((c, r))
-        r, c = r[order], c[order]
+        r, c = sort_two_keys(r, c)
         vmask = r < m
         cap = r.shape[0]
         new_key = jnp.ones((cap,), dtype=bool)

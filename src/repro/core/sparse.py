@@ -74,8 +74,9 @@ class SparseCOO:
 
         ``engine="auto"`` packs (row, col) into one monotonic i32 key and runs
         a single-key ``lax.sort`` (stable — bit-identical to the lexsort path);
-        ``"lexsort"`` forces the seed's two-key path (parity reference, and
-        the fallback when the packed key would overflow i32).
+        ``"lexsort"`` forces the two-key sort carrying the values, no
+        permutation gathers (parity reference, and the fallback when the
+        packed key would overflow i32).
         """
         m, n = self.shape
         if engine != "lexsort" and sortkeys.fits_i32(m, n):
@@ -83,23 +84,24 @@ class SparseCOO:
             key, vals = jax.lax.sort((key, self.vals), num_keys=1)
             rows, cols = sortkeys.unpack_rowmajor(key, n)
             return SparseCOO(rows, cols, vals, self.nnz, self.shape)
-        order = jnp.lexsort((self.cols, self.rows))
-        return SparseCOO(
-            self.rows[order], self.cols[order], self.vals[order], self.nnz, self.shape
-        )
+        rows, cols, vals = sortkeys.sort_two_keys(self.rows, self.cols, self.vals)
+        return SparseCOO(rows, cols, vals, self.nnz, self.shape)
 
     def sort_colmajor(self, engine: str = "auto") -> "SparseCOO":
-        """Sort entries by (col, row) — CSC-like ordering used by local SpGEMM."""
+        """Sort entries by (col, row) — CSC-like ordering used by local SpGEMM.
+
+        Engines as in ``sort_rowmajor``: one packed key when (col, row) fits
+        i32, else (and for ``"lexsort"``) the two-key sort carrying the
+        values, no permutation gathers.
+        """
         m, n = self.shape
         if engine != "lexsort" and sortkeys.fits_i32(m, n):
             key = sortkeys.pack_colmajor(self.rows, self.cols, m)
             key, vals = jax.lax.sort((key, self.vals), num_keys=1)
             rows, cols = sortkeys.unpack_colmajor(key, m)
             return SparseCOO(rows, cols, vals, self.nnz, self.shape)
-        order = jnp.lexsort((self.rows, self.cols))
-        return SparseCOO(
-            self.rows[order], self.cols[order], self.vals[order], self.nnz, self.shape
-        )
+        cols, rows, vals = sortkeys.sort_two_keys(self.cols, self.rows, self.vals)
+        return SparseCOO(rows, cols, vals, self.nnz, self.shape)
 
     # ------------------------------------------------------------- reshaping
     def with_capacity(self, new_cap: int) -> "SparseCOO":
@@ -323,7 +325,8 @@ def coalesce(a: SparseCOO, new_cap: int, engine: str = "auto"):
     the sparse path. Returns (merged, overflow count). ``engine`` selects the
     packed-key sort/compress path (see ``repro.core.sortkeys``): "auto" uses
     the sort-free bucket scan for small key spaces, a single-key packed sort
-    otherwise, and "lexsort" pins the seed's two-key reference path.
+    otherwise, and "lexsort" pins the two-key sort carrying the values (no
+    permutation gathers), the path for key spaces past i32.
     """
     m, n = a.shape
     rows, cols, vals, nnz, overflow = sortkeys.coalesce_entries(

@@ -74,8 +74,12 @@ except ImportError:
                     drawn = {k: s.draw(rng) for k, s in strats.items()}
                     fn(*args, **drawn, **kwargs)
 
-            # pytest must not see the property parameters as fixtures
-            runner.__signature__ = inspect.Signature()
+            # pytest must not see the property parameters as fixtures; it
+            # keeps seeing the rest (fixtures, ``parametrize`` arguments)
+            sig = inspect.signature(fn)
+            runner.__signature__ = sig.replace(parameters=[
+                p for name, p in sig.parameters.items() if name not in strats
+            ])
             runner.__wrapped__ = None
             del runner.__wrapped__
             return runner

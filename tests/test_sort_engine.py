@@ -31,6 +31,80 @@ def random_entries(rng, m, n, cap, valid_p=0.8):
     return rows, cols, vals, valid
 
 
+def duplicate_heavy_entries(rng, cap=256):
+    """Entries on a 4 x 3 tile (~21 per coordinate) whose values span eight
+    decades with both signs, so a coordinate's f32 sum depends on the order
+    its entries are added in."""
+    m, n = 4, 3
+    rows, cols, _, valid = random_entries(rng, m, n, cap, valid_p=0.9)
+    mag = 10.0 ** rng.integers(-4, 5, cap)
+    vals = (rng.standard_normal(cap) * mag).astype(np.float32)
+    return (m, n), (rows, cols, jnp.asarray(vals), valid)
+
+
+def lexsort_gather_coalesce(rows, cols, vals, valid, shape, new_cap, add_kind):
+    """The compress as it was written with a permutation: ``jnp.lexsort``,
+    rows, cols and values gathered by it, then the boundary scan and the
+    slot reduction. The reference the two-key engine must match bit for
+    bit."""
+    m, n = shape
+    rows = jnp.where(valid, rows, m)
+    cols = jnp.where(valid, cols, n)
+    order = jnp.lexsort((cols, rows))
+    rows, cols, vals = rows[order], cols[order], vals[order]
+    vmask = rows < m
+    new_key = jnp.concatenate([
+        jnp.ones((1,), bool),
+        (rows[1:] != rows[:-1]) | (cols[1:] != cols[:-1]),
+    ]) & vmask
+    seg = jnp.cumsum(new_key.astype(jnp.int32)) - 1
+    total = jnp.maximum(seg[-1] + 1, 0)
+    seg = jnp.where(vmask & (seg < new_cap), seg, new_cap)
+    out_rows = jnp.full((new_cap + 1,), m, jnp.int32).at[seg].min(rows)
+    out_cols = jnp.full((new_cap + 1,), n, jnp.int32).at[seg].min(cols)
+    if add_kind == "sum":
+        out_vals = jnp.zeros((new_cap + 1,), vals.dtype).at[seg].add(
+            jnp.where(seg < new_cap, vals, 0)
+        )
+    elif add_kind == "min":
+        out_vals = jnp.full((new_cap + 1,), jnp.inf, vals.dtype).at[seg].min(vals)
+    else:
+        out_vals = jnp.full((new_cap + 1,), -jnp.inf, vals.dtype).at[seg].max(vals)
+    nnz = jnp.minimum(total, new_cap).astype(jnp.int32)
+    pad = jnp.arange(new_cap) >= nnz
+    return (
+        jnp.where(pad, m, out_rows[:new_cap]),
+        jnp.where(pad, n, out_cols[:new_cap]),
+        jnp.where(pad, 0, out_vals[:new_cap]).astype(vals.dtype),
+        nnz,
+        (total - nnz).astype(jnp.int32),
+    )
+
+
+def lexsort_gather_sort(a, primary, secondary):
+    """``a`` sorted by ``jnp.lexsort`` on two of its fields plus gathers."""
+    order = jnp.lexsort((getattr(a, secondary), getattr(a, primary)))
+    return tuple(np.asarray(getattr(a, f)[order]) for f in ("rows", "cols", "vals"))
+
+
+def with_duplicates(a, rng):
+    """``a`` with every entry repeated once under a fresh value (the copy's
+    padding included), so equal coordinates must keep their input order."""
+    fresh = jnp.asarray(rng.random(a.cap).astype(np.float32))
+    return sp.SparseCOO(
+        jnp.concatenate([a.rows, a.rows]), jnp.concatenate([a.cols, a.cols]),
+        jnp.concatenate([a.vals, fresh]), a.nnz, a.shape,
+    )
+
+
+def equations(jaxpr):
+    """Every equation of ``jaxpr``, nested jaxprs (jit, cumsum) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from equations(sub)
+
+
 def assert_entries_equal(got, want, context=""):
     for name, x, y in zip(("rows", "cols", "vals", "nnz", "ovf"), got, want):
         np.testing.assert_allclose(
@@ -48,50 +122,92 @@ class TestPackedSortParity:
     def test_sort_rowmajor_bitexact(self, seed, density):
         rng = np.random.default_rng(seed)
         x = dense_random(rng, 13, 11, density)
-        a = sp.from_dense(jnp.asarray(x), cap=13 * 11 + 5)
+        a = with_duplicates(sp.from_dense(jnp.asarray(x), cap=13 * 11 + 5), rng)
         packed = a.sort_rowmajor(engine="auto")
         legacy = a.sort_rowmajor(engine="lexsort")
-        for f in ("rows", "cols", "vals"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(packed, f)), np.asarray(getattr(legacy, f)), f
-            )
+        ref = lexsort_gather_sort(a, "rows", "cols")
+        for f, want in zip(("rows", "cols", "vals"), ref):
+            np.testing.assert_array_equal(np.asarray(getattr(packed, f)), want, f)
+            np.testing.assert_array_equal(np.asarray(getattr(legacy, f)), want, f)
 
     @settings(max_examples=15, deadline=None)
     @given(seed=st.integers(0, 2**16), density=st.floats(0.05, 0.6))
     def test_sort_colmajor_bitexact(self, seed, density):
         rng = np.random.default_rng(seed)
         x = dense_random(rng, 9, 17, density)
-        a = sp.from_dense(jnp.asarray(x), cap=9 * 17 + 3)
+        a = with_duplicates(sp.from_dense(jnp.asarray(x), cap=9 * 17 + 3), rng)
         packed = a.sort_colmajor(engine="auto")
         legacy = a.sort_colmajor(engine="lexsort")
-        for f in ("rows", "cols", "vals"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(packed, f)), np.asarray(getattr(legacy, f)), f
-            )
+        ref = lexsort_gather_sort(a, "cols", "rows")
+        for f, want in zip(("rows", "cols", "vals"), ref):
+            np.testing.assert_array_equal(np.asarray(getattr(packed, f)), want, f)
+            np.testing.assert_array_equal(np.asarray(getattr(legacy, f)), want, f)
+
+    @pytest.mark.parametrize("op", ["coalesce", "count_unique", "sort_rowmajor",
+                                    "sort_colmajor"])
+    def test_lexsort_engine_is_one_two_key_sort(self, op):
+        """The two-key engine sorts once on (row, col), or (col, row), with
+        the values riding along: no permutation is gathered by."""
+        m, n, cap = 1 << 18, 1 << 14, 64
+        rows = jnp.zeros((cap,), jnp.int32)
+        vals = jnp.zeros((cap,), jnp.float32)
+        valid = jnp.ones((cap,), bool)
+        fns = {
+            "coalesce": lambda r, c, v: sk.coalesce_entries(
+                r, c, v, valid, (m, n), 32, "sum", "lexsort"),
+            "count_unique": lambda r, c, v: sk.count_unique(
+                r, c, valid, (m, n), "lexsort"),
+            "sort_rowmajor": lambda r, c, v: sp.SparseCOO(
+                r, c, v, jnp.int32(cap), (m, n)).sort_rowmajor("lexsort"),
+            "sort_colmajor": lambda r, c, v: sp.SparseCOO(
+                r, c, v, jnp.int32(cap), (m, n)).sort_colmajor("lexsort"),
+        }
+        eqns = list(equations(jax.make_jaxpr(fns[op])(rows, rows, vals).jaxpr))
+        sorts = [e for e in eqns if e.primitive.name == "sort"]
+        assert [e.params["num_keys"] for e in sorts] == [2]
+        assert sorts[0].params["is_stable"]
+        assert not [e for e in eqns if e.primitive.name == "gather"]
 
 
 # ---------------------------------------------------------------------------
 # coalesce engines parity over semirings
 # ---------------------------------------------------------------------------
 class TestCoalesceEngines:
-    @settings(max_examples=12, deadline=None)
-    @given(
-        seed=st.integers(0, 2**16),
-        ring=st.sampled_from(["plus_times", "min_plus", "max_times"]),
-    )
-    def test_engines_match_lexsort(self, seed, ring):
+    @pytest.mark.parametrize("ring", ["plus_times", "min_plus", "max_times"])
+    @pytest.mark.parametrize("inputs", ["scattered", "duplicate_heavy"])
+    @settings(max_examples=6, deadline=None)
+    @given(seed=st.integers(0, 2**16))
+    def test_engines_match_lexsort(self, seed, ring, inputs):
+        """The two-key engine equals ``jnp.lexsort`` plus gathers bit for
+        bit; the packed and bucket engines equal it to rounding."""
         semiring = sr.get(ring)
         rng = np.random.default_rng(seed)
-        m, n, cap, new_cap = 14, 10, 96, 64
-        rows, cols, vals, valid = random_entries(rng, m, n, cap)
-        ref = sk.coalesce_entries(
-            rows, cols, vals, valid, (m, n), new_cap, semiring.add_kind, "lexsort"
+        if inputs == "scattered":
+            shape, new_cap = (14, 10), 64
+            entries = random_entries(rng, *shape, 96)
+        else:
+            shape, new_cap = (4, 3), 12
+            entries = duplicate_heavy_entries(rng)[1]
+            if ring == "plus_times":
+                # the input must tell summation orders apart
+                rows, cols, vals, valid = entries
+                fwd = lexsort_gather_coalesce(*entries, shape, new_cap, "sum")[2]
+                rev = lexsort_gather_coalesce(
+                    rows[::-1], cols[::-1], vals[::-1], valid[::-1],
+                    shape, new_cap, "sum")[2]
+                assert not np.array_equal(np.asarray(fwd), np.asarray(rev))
+        ref = lexsort_gather_coalesce(*entries, shape, new_cap, semiring.add_kind)
+        got = sk.coalesce_entries(
+            *entries, shape, new_cap, semiring.add_kind, "lexsort"
         )
-        for eng in ("packed", "bucket"):
-            got = sk.coalesce_entries(
-                rows, cols, vals, valid, (m, n), new_cap, semiring.add_kind, eng
-            )
-            assert_entries_equal(got, ref, f"{ring}/{eng}")
+        for name, x, y in zip(("rows", "cols", "vals", "nnz", "ovf"), got, ref):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y), name)
+        if inputs == "scattered":
+            for eng in ("packed", "bucket"):
+                got = sk.coalesce_entries(
+                    *entries, shape, new_cap, semiring.add_kind, eng
+                )
+                assert_entries_equal(got, ref, f"{ring}/{eng}")
 
     def test_auto_picks_bucket_for_small_tiles(self):
         assert sk.choose_engine(100, 100, 1000) == "bucket"
@@ -100,9 +216,13 @@ class TestCoalesceEngines:
         big = 1 << 13
         assert sk.choose_engine(big, big, 1000) == "packed"
 
-    def test_lexsort_when_key_overflows_i32(self):
-        big = 1 << 17
-        assert sk.choose_engine(big, big, 1000) == "lexsort"
+    @pytest.mark.parametrize("shape", [
+        (1 << 17, 1 << 17),
+        (1 << 18, 1 << 14),  # protein-2e18.square-sync's batch tile (b = 16)
+        (1 << 20, 1 << 11),  # rmat-s20.square-sync's batch tile (b = 512)
+    ])
+    def test_lexsort_when_key_overflows_i32(self, shape):
+        assert sk.choose_engine(*shape, 1000) == "lexsort"
 
     @settings(max_examples=10, deadline=None)
     @given(seed=st.integers(0, 2**16))
